@@ -20,8 +20,8 @@ import os
 import jax
 import numpy as np
 
-# float64 end to end, so the jitted JAX backend is bit-identical to the
-# eager NumPy interpreter on this elementwise program
+# float64 end to end, so on the CPU the jitted JAX backend is
+# bit-identical to the eager NumPy interpreter on this elementwise program
 jax.config.update("jax_enable_x64", True)
 
 import repro
@@ -29,6 +29,10 @@ from repro.api import ExecutionPolicy, RuntimeConfig, format_stats
 
 SYNC = os.environ.get("REPRO_SYNC", "auto")
 N, ITERS = 1024, 6
+# backends must agree bit-for-bit on the CPU, where both compute in
+# float64 with the same IEEE ops; an accelerator's XLA may emulate or
+# narrow float64, so there they must agree to this absolute tolerance
+BACKEND_ATOL = 1e-6
 
 
 def jacobi_stencil(n: int, iters: int) -> np.ndarray:
@@ -81,8 +85,9 @@ print(f"\nlatency-hiding wall-clock win: {st_bl.makespan/st_lh.makespan:.2f}x "
 # injected per message so there is real latency to hide.  The wait%
 # here is MEASURED on the wall clock; the simulated rows model the same
 # α, rendered in the same table by format_stats.  Both registered
-# compute backends drain the same graphs and must agree bit-for-bit
-# (float64 everywhere, elementwise IEEE ops).
+# compute backends drain the same graphs and must agree bit-for-bit on
+# the CPU (float64 everywhere, elementwise IEEE ops), and to
+# BACKEND_ATOL on an accelerator.
 #
 # The async flush runs the record→plan→execute pipeline: with the
 # default passes="auto", transfers are coalesced into fewer, larger
@@ -111,7 +116,10 @@ for backend in backends:
     np.testing.assert_array_equal(r_on, r_off)
     if reference is None:
         reference = r_on
-    np.testing.assert_array_equal(r_on, reference)  # backends agree bit-for-bit
+    if jax.default_backend() == "cpu":
+        np.testing.assert_array_equal(r_on, reference)
+    else:
+        np.testing.assert_allclose(r_on, reference, rtol=0, atol=BACKEND_ATOL)
 
     print(f"\nmeasured vs simulated ({MN}x{MN}, {MPROCS} workers, "
           f"backend={backend!r}):")
@@ -173,14 +181,17 @@ if TRACE not in ("", "0", "false", "False"):
     )
     print("attribution names the halo-exchange transfers as top wait source ✓")
 
-# --- the same schedule as a compiled TPU/XLA program --------------------
-# (runs on CPU here; on a TPU pod the ppermute halo exchange overlaps the
-# interior update via async collective-permute — DESIGN.md §3)
+# --- the fused sweep as one Pallas kernel -------------------------------
+# The kernel runs in the Pallas interpreter on the CPU platform and is
+# compiled everywhere else.  The same sweep sharded over chips, with the
+# halo rows exchanged by ppermute while the interior updates, is
+# repro.comm.collectives.jacobi_step_sharded (`chip_smoke.py --chips 4`
+# runs it on a four-chip mesh).
 import jax.numpy as jnp
 from repro.kernels.stencil import jacobi_sweep, jacobi_sweep_ref
 
 g = jnp.asarray(np.random.default_rng(0).random((256, 256)), jnp.float32)
-fused = jacobi_sweep(g, band=64)          # Pallas kernel (interpret=True)
+fused = jacobi_sweep(g, band=64)          # Pallas kernel
 ref = jacobi_sweep_ref(g)                  # 5-view jnp chain (paper's form)
 print(f"\nPallas fused-sweep kernel matches the 5-view reference: "
       f"{bool(jnp.allclose(fused, ref, atol=1e-6))}")

@@ -39,7 +39,16 @@ EXAMPLES = ("examples/quickstart.py", "examples/stencil_latency_hiding.py")
 def lint_example(path: str, timeout: float = 900.0) -> dict:
     """Run one example with full verification enabled in its
     environment; a verification failure (or any crash) fails the
-    child."""
+    child.
+
+    The child may need the accelerator, which one process holds at a
+    time, so this process must not have imported JAX: the in-process
+    lints run only after every child has exited."""
+    if "jax" in sys.modules:
+        raise RuntimeError(
+            "graph-lint imported JAX before starting an example; the "
+            "child could not reach the accelerator"
+        )
     env = dict(os.environ)
     env["REPRO_VERIFY"] = "full"
     env["PYTHONPATH"] = os.pathsep.join(

@@ -32,20 +32,6 @@ __all__ = [
 ]
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis, across the jax API change:
-    ``lax.axis_size`` (jax >= 0.5) vs ``jax.core.axis_frame`` returning
-    the size directly (jax <= 0.4.x)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    import jax as _jax
-
-    frame = _jax.core.axis_frame(axis_name)
-    # late 0.4.3x returns the int size directly; earlier 0.4.x return an
-    # AxisEnvFrame carrying it
-    return getattr(frame, "size", frame)
-
-
 def _fwd_perm(n: int):
     """ring: rank i sends to i+1 (accumulators travel forward)."""
     return [(i, (i + 1) % n) for i in range(n)]
@@ -67,7 +53,7 @@ def ring_all_gather(x: jax.Array, axis_name: str, *, axis: int = 0) -> jax.Array
     Returns the gathered array with shard blocks concatenated along
     ``axis`` in rank order.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     shape = list(x.shape)
     size_local = shape[axis]
@@ -102,7 +88,7 @@ def ring_reduce_scatter(
     each step's ppermute with the *next* partial's computation (the paper's
     sub-view-block interleave).
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
 
     if callable(partials):
@@ -148,7 +134,7 @@ def ag_matmul(
     overlap="none": one blocking all-gather then one matmul (paper's
     blocking baseline).
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if overlap == "none" or n == 1:
         xg = lax.all_gather(x, axis_name, axis=gather_axis % x.ndim, tiled=True)
         return xg @ w
@@ -190,7 +176,7 @@ def matmul_rs(
     just-in-time while the accumulator ring-permutes (each hop overlapped).
     overlap="none": full matmul then one blocking psum_scatter.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if overlap == "none" or n == 1:
         y = x @ w
         return lax.psum_scatter(y, axis_name, scatter_dimension=scatter_axis % y.ndim, tiled=True)
@@ -224,7 +210,7 @@ def halo_exchange(
     previous/next rank along ``axis_name``.  Non-periodic boundaries get
     zero slabs (masked after the permute so the wire pattern is uniform).
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     L = u.shape[axis]
 
@@ -286,7 +272,7 @@ def jacobi_step_sharded(
     interior is updated with the classic 0.2·(c+u+d+l+r) rule from the
     paper's Jacobi-Stencil benchmark (fig. 10).
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     L = full.shape[0]
 

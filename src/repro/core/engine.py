@@ -1864,6 +1864,13 @@ class Runtime:
         self._dead_bases = set()
 
     # -- reporting -------------------------------------------------------------
+    def backend_stats(self) -> dict:
+        """The async compute backend's per-path payload counters (see
+        ``JaxBackend.stats``); empty before the first async drain and
+        after :meth:`close`."""
+        backend = self._exec_backend_obj
+        return {} if backend is None else backend.stats()
+
     def stats(self):
         """Accumulated run statistics: the simulated
         :class:`TimelineResult`, or the measured

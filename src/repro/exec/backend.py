@@ -78,6 +78,10 @@ class ComputeBackend:
     def execute(self, op: OperationNode) -> None:
         raise NotImplementedError
 
+    def stats(self) -> dict:
+        """Per-path payload counters (empty for a backend without any)."""
+        return {}
+
 
 class NumpyBackend(ComputeBackend):
     """Eager NumPy interpretation — the reference backend (bit-identical
@@ -95,12 +99,17 @@ class JaxBackend(ComputeBackend):
     * Elementwise map payloads (including fused expression trees, via
       ``UFunc.tree``) are retraced with ``jax.numpy`` primitives and
       cached per (ufunc, signature).
-    * Fused 5-point stencil payloads are routed through the Pallas
-      ``stencil5_block`` kernel from ``repro.kernels.stencil`` (interpret
-      mode on CPU, compiled on TPU).
+    * Fused 5-point stencil payloads of 32-bit (or narrower) dtype are
+      routed through the Pallas ``stencil5_block`` kernel from
+      ``repro.kernels.stencil`` (interpreted on the CPU platform,
+      compiled everywhere else).  Mosaic has no 64-bit types, so under
+      ``jax_enable_x64`` float64 stencils take the jitted ``jnp`` path.
     * Matmul payloads run through a jitted ``jnp.dot``.
-    * Everything else (transfers, reductions, fills) falls back to the
-      NumPy interpreter — those are memory movement, not FLOPs.
+    * Everything else (transfers, reductions, fills) runs on the NumPy
+      interpreter — those are memory movement, not FLOPs.
+
+    :meth:`stats` counts payloads per path, including map payloads that
+    had to run on the host because a ufunc in them has no ``jnp`` form.
 
     Note: without ``jax_enable_x64`` the payloads compute in float32, so
     results are *numerically close*, not bit-identical, to the NumPy
@@ -111,9 +120,14 @@ class JaxBackend(ComputeBackend):
 
     def __init__(self, storage: dict, scratch: dict):
         super().__init__(storage, scratch)
-        import jax  # the container bakes in the jax toolchain
+        import jax
         import jax.numpy as jnp
 
+        from repro.compile_cache import enable_compile_cache
+        from repro.kernels import resolve_interpret
+        from repro.kernels.stencil import stencil5_block
+
+        enable_compile_cache()
         self._jax = jax
         self._jnp = jnp
         self._x64 = bool(jax.config.read("jax_enable_x64"))
@@ -140,16 +154,29 @@ class JaxBackend(ComputeBackend):
         }
         self._jit_cache: dict = {}
         self._untranslatable: set = set()  # (name, tree_key) with no jnp form
-        # interpret the Pallas kernel everywhere but on a real TPU
-        self._interpret = jax.default_backend() != "tpu"
-        try:
-            from repro.kernels.stencil import stencil5_block
-
-            self._stencil5 = stencil5_block
-        except Exception:  # pragma: no cover - kernels unavailable
-            self._stencil5 = None
+        self._stencil5 = stencil5_block
+        self.interpret = resolve_interpret(None)
+        # payload counters, bumped from every worker thread
+        self._count_lock = threading.Lock()
+        self._counts = dict(
+            n_jit=0,  # maps and matmuls run as jitted XLA programs
+            n_pallas=0,  # fused stencils run through the Pallas kernel
+            n_host_untranslated=0,  # maps with no jnp form, run by NumPy
+            n_host=0,  # reductions, fills: NumPy by design (transfers
+                       # run on the channel, not here)
+        )
 
     # -- helpers ---------------------------------------------------------
+    def _count(self, key: str) -> None:
+        with self._count_lock:
+            self._counts[key] += 1
+
+    def stats(self) -> dict:
+        """Payload counts per execution path, plus whether the Pallas
+        kernel runs interpreted (only on the CPU platform)."""
+        with self._count_lock:
+            return dict(self._counts, interpret=self.interpret)
+
     def _to_device(self, x):
         jnp = self._jnp
         if isinstance(x, np.ndarray) and not self._x64:
@@ -226,9 +253,13 @@ class JaxBackend(ComputeBackend):
         if isinstance(p, MapPayload):
             if self._exec_map(p):
                 return
+            self._count("n_host_untranslated")
         elif isinstance(p, MatmulPayload):
             self._exec_matmul(p)
+            self._count("n_jit")
             return
+        else:
+            self._count("n_host")
         execute_payload(p, self.storage, self.scratch)
 
     def _exec_map(self, p: MapPayload) -> bool:
@@ -237,28 +268,31 @@ class JaxBackend(ComputeBackend):
             return False  # known fallback: skip resolving refs twice
         args = [resolve_ref(r, self.storage, self.scratch) for r in p.args]
         arr_idx = [i for i, r in enumerate(p.args) if r[0] != "c"]
-        # Pallas fast path: fused 5-point stencil block sweep
+        dev_args = list(args)
+        for i in arr_idx:
+            dev_args[i] = self._to_device(np.ascontiguousarray(args[i]))
+        # Pallas fast path: fused 5-point stencil block sweep (32-bit or
+        # narrower only: Mosaic has no 64-bit types)
         if (
-            self._stencil5 is not None
-            and p.ufunc.tree is not None
+            p.ufunc.tree is not None
             and len(arr_idx) == 5
-            and all(getattr(args[i], "ndim", 0) == 2 for i in arr_idx)
-            and len({args[i].shape for i in arr_idx}) == 1
+            and all(dev_args[i].ndim == 2 for i in arr_idx)
+            and len({dev_args[i].shape for i in arr_idx}) == 1
+            and all(dev_args[i].dtype.itemsize <= 4 for i in arr_idx)
         ):
             w = self._stencil5_weight(p.ufunc.tree)
             if w is not None:
-                xs = [self._to_device(np.ascontiguousarray(args[i])) for i in arr_idx]
-                res = self._stencil5(*xs, weight=w, interpret=self._interpret)
+                xs = [dev_args[i] for i in arr_idx]
+                res = self._stencil5(*xs, weight=w, interpret=self.interpret)
                 self._store(p, np.asarray(res))
+                self._count("n_pallas")
                 return True
         fn = self._cached_jit(p, args, arr_idx)
         if fn is None:
             self._untranslatable.add(ukey)
             return False
-        dev_args = list(args)
-        for i in arr_idx:
-            dev_args[i] = self._to_device(np.ascontiguousarray(args[i]))
         self._store(p, np.asarray(fn(*dev_args)))
+        self._count("n_jit")
         return True
 
     @staticmethod
@@ -342,25 +376,23 @@ class AutoBackend(ComputeBackend):
         self.threshold = threshold
         self._numpy = NumpyBackend(storage, scratch)
         self._jax: Optional[JaxBackend] = None
-        self._jax_unavailable = False
+        self._jax_lock = threading.Lock()
         self.n_numpy = 0
         self.n_jax = 0
 
-    def _jax_backend(self) -> Optional[JaxBackend]:
-        if self._jax is None and not self._jax_unavailable:
-            try:
+    def _jax_backend(self) -> JaxBackend:
+        with self._jax_lock:  # workers race to the first heavy payload
+            if self._jax is None:
                 self._jax = JaxBackend(self.storage, self.scratch)
-            except ImportError as exc:  # no usable jax: degrade to NumPy
-                self._jax_unavailable = True
-                import warnings
+            return self._jax
 
-                warnings.warn(
-                    f"backend='auto': jax unavailable ({exc}); all payloads "
-                    f"will run on the NumPy interpreter",
-                    RuntimeWarning,
-                    stacklevel=3,
-                )
-        return self._jax
+    def stats(self) -> dict:
+        """Routing counts, plus the JAX backend's per-path counters once
+        it has been built."""
+        out = dict(n_numpy=self.n_numpy, n_jax=self.n_jax)
+        if self._jax is not None:
+            out.update(self._jax.stats())
+        return out
 
     def _score(self, p) -> float:
         if isinstance(p, MapPayload):
@@ -371,11 +403,9 @@ class AutoBackend(ComputeBackend):
 
     def execute(self, op: OperationNode) -> None:
         if self._score(op.payload) >= self.threshold:
-            jb = self._jax_backend()
-            if jb is not None:
-                self.n_jax += 1
-                jb.execute(op)
-                return
+            self.n_jax += 1
+            self._jax_backend().execute(op)
+            return
         self.n_numpy += 1
         self._numpy.execute(op)
 

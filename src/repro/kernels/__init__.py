@@ -3,7 +3,7 @@
 Each kernel ships three files per the repo convention:
 ``kernel.py`` (pl.pallas_call + BlockSpec VMEM tiling), ``ops.py``
 (jit'd public wrapper: padding/layout/GQA broadcast) and ``ref.py``
-(pure-jnp oracle the tests sweep against, interpret=True on CPU).
+(pure-jnp oracle the tests sweep against).
 
 * ``stencil``         — fused 5-point Jacobi sweep: the paper's flagship
                          app (§6) with its §7 ufunc-merging implemented
@@ -11,14 +11,19 @@ Each kernel ships three files per the repo convention:
 * ``flash_attention`` — causal/GQA/SWA online-softmax attention.
 * ``mamba2_scan``     — chunked SSD scan (zamba2's mixer).
 * ``rwkv6_wkv``       — chunked data-dependent-decay wkv recurrence.
+
+Every wrapper takes ``interpret=None``, resolved by
+:func:`resolve_interpret`: the Pallas interpreter runs on the CPU
+platform only, so a kernel called on an accelerator is always compiled.
 """
+from typing import Optional
 
 
-def tpu_compiler_params(**kwargs):
-    """Build TPU compiler params across the pallas API rename:
-    ``pltpu.TPUCompilerParams`` (jax ≤ 0.4.x) became
-    ``pltpu.CompilerParams`` (jax ≥ 0.5)."""
-    from jax.experimental.pallas import tpu as pltpu
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """``None`` means: interpret on the CPU platform, compile elsewhere.
+    An explicit bool is kept (compile-only tests pass ``False`` on CPU)."""
+    if interpret is not None:
+        return interpret
+    import jax
 
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return jax.default_backend() == "cpu"

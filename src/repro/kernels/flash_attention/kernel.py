@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -143,7 +141,7 @@ def flash_attention_kernel(
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )(q, k, v)

@@ -2,8 +2,8 @@
 
 Handles layout ([B,S,H,d] ↔ [B,H,S,d]), GQA head broadcast, head-dim
 padding to the 128-lane MXU width, and sequence padding to block
-multiples.  ``interpret=True`` (the CPU default here) runs the kernel
-body in Python for validation; on a real TPU pass ``interpret=False``.
+multiples.  ``interpret=None`` runs the kernel body in the Pallas
+interpreter on the CPU platform and compiles it everywhere else.
 """
 from __future__ import annotations
 
@@ -12,6 +12,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import resolve_interpret
 
 from .kernel import flash_attention_kernel
 
@@ -30,7 +32,7 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     B, Sq, H, d = q.shape
     Sk, KV = k.shape[1], k.shape[2]
@@ -68,7 +70,8 @@ def flash_attention(
     out = flash_attention_kernel(
         qt, kt, vt,
         causal=causal, window=window, scale=scale,
-        block_q=bq, block_k=bk, sk_valid=Sk, interpret=interpret,
+        block_q=bq, block_k=bk, sk_valid=Sk,
+        interpret=resolve_interpret(interpret),
     )
     out = out.transpose(0, 2, 1, 3)[:, :Sq, :, :d]
     return out

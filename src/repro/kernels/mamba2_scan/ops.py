@@ -7,6 +7,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
+
 from .kernel import ssd_scan_kernel
 
 
@@ -20,7 +22,7 @@ def ssd_scan(
     init_state: Optional[jax.Array] = None,  # [b, h, p, n]
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     b, s, h, p = x.shape
     n = B.shape[-1]
@@ -37,7 +39,10 @@ def ssd_scan(
         if init_state is None
         else init_state.astype(jnp.float32)
     )
-    y, fin = ssd_scan_kernel(x, dt, A, B, C, s0, chunk=chunk, interpret=interpret)
+    y, fin = ssd_scan_kernel(
+        x, dt, A, B, C, s0, chunk=chunk,
+        interpret=resolve_interpret(interpret),
+    )
     if pad:
         y = y[:, :s]
     return y, fin

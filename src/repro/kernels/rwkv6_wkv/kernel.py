@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _wkv_kernel(
     r_ref, k_ref, v_ref, w_ref,  # [1, c, 1, N]
@@ -110,7 +108,7 @@ def wkv6_kernel(r, k, v, w, u, s0, *, chunk: int = 64, interpret: bool = False):
         ],
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(r, k, v, w, u, s0)

@@ -7,6 +7,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
+
 from .kernel import wkv6_kernel
 
 
@@ -20,7 +22,7 @@ def wkv6(
     init_state: Optional[jax.Array] = None,  # [B, H, N, N]
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     B, T, H, N = r.shape
     chunk = min(chunk, T)
@@ -34,7 +36,10 @@ def wkv6(
         if init_state is None
         else init_state.astype(jnp.float32)
     )
-    y, fin = wkv6_kernel(r, k, v, w, u, s0, chunk=chunk, interpret=interpret)
+    y, fin = wkv6_kernel(
+        r, k, v, w, u, s0, chunk=chunk,
+        interpret=resolve_interpret(interpret),
+    )
     if pad:
         y = y[:, :T]
     return y, fin

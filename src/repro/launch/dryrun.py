@@ -29,7 +29,11 @@ from repro.configs import SHAPES, all_arch_ids  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.sharding import shardings  # noqa: E402
 from repro.launch.steps import cell, skip_reason  # noqa: E402
-from repro.roofline.analysis import analyze_compiled, model_flops, roofline_terms  # noqa: E402
+from repro.roofline.analysis import analyze_compiled, model_flops, peaks_for, roofline_terms  # noqa: E402
+
+# the 16x16 and 2x16x16 meshes stand for v5e pods; the placeholder host
+# devices carry no device kind of their own
+TARGET_HW = peaks_for("TPU v5 lite")
 
 DEFAULT_OUT = Path("results/dryrun")
 
@@ -63,7 +67,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, out_dir: Path,
         t_compile = time.time() - t0 - t_lower
         analysis = analyze_compiled(compiled, n_devices=n_dev)
         mf = model_flops(c.cfg, c.shape)
-        terms = roofline_terms(analysis, n_devices=n_dev)
+        terms = roofline_terms(analysis, n_devices=n_dev, hw=TARGET_HW)
         rec.update(
             status="ok",
             kind=c.kind,
@@ -178,7 +182,7 @@ def run_cost_probe(arch: str, shape_name: str, *, multi_pod: bool, out_dir: Path
         }
         c_full = cell(arch, shape_name, mesh, **(overrides or {}))
         mf = model_flops(c_full.cfg, c_full.shape)
-        terms = roofline_terms(analysis, n_devices=n_dev)
+        terms = roofline_terms(analysis, n_devices=n_dev, hw=TARGET_HW)
         rec.update(
             status="ok", kind=c_full.kind, n_devices=n_dev,
             model_flops=mf,

@@ -18,12 +18,12 @@ import time
 import numpy as np
 
 
-def tenant_workload(seed: int, n: int):
+def tenant_workload(seed: int, n: int, dtype=np.float64):
     """One tenant's request: a 5-point stencil step over a private
     array, plus its NumPy closed form for verification."""
     import repro
 
-    host = np.random.default_rng(seed).standard_normal((n, n))
+    host = np.random.default_rng(seed).standard_normal((n, n)).astype(dtype)
 
     def fn():
         a = repro.array(host)
@@ -31,9 +31,10 @@ def tenant_workload(seed: int, n: int):
              + np.roll(a, 1, axis=1) + np.roll(a, -1, axis=1)) * 0.25
         return b - a * 0.5
 
-    expect = (np.roll(host, 1, axis=0) + np.roll(host, -1, axis=0)
-              + np.roll(host, 1, axis=1) + np.roll(host, -1, axis=1)) * 0.25 \
-        - host * 0.5
+    ref = host.astype(np.float64)  # the closed form in float64
+    expect = (np.roll(ref, 1, axis=0) + np.roll(ref, -1, axis=0)
+              + np.roll(ref, 1, axis=1) + np.roll(ref, -1, axis=1)) * 0.25 \
+        - ref * 0.5
     return fn, expect
 
 

@@ -25,6 +25,8 @@ from dataclasses import dataclass, field
 
 __all__ = [
     "HW",
+    "PEAKS",
+    "peaks_for",
     "CollectiveStats",
     "collective_bytes",
     "analyze_compiled",
@@ -35,13 +37,38 @@ __all__ = [
 
 @dataclass(frozen=True)
 class HW:
-    """TPU v5e-class constants (per chip)."""
+    """Per-chip peaks of one accelerator kind."""
 
-    peak_flops: float = 197e12  # bf16
-    hbm_bw: float = 819e9  # B/s
-    ici_bw: float = 50e9  # B/s per link class (intra-pod)
-    dci_bw: float = 25e9  # B/s cross-pod ("pod" axis)
-    hbm_bytes: float = 16e9  # capacity
+    peak_flops: float  # bf16 FLOP/s
+    hbm_bw: float  # B/s
+    ici_bw: float  # B/s per link class (intra-pod)
+    dci_bw: float  # B/s cross-pod ("pod" axis)
+    hbm_bytes: float  # capacity
+
+
+# Keyed by ``jax.Device.device_kind``.  TPU v5e: Google Cloud
+# documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s of inter-chip interconnect (4 links of 50 GB/s).  The
+# cross-pod rate is an assumption of the dry-run's 2-pod mesh, not a
+# published figure.
+PEAKS = {
+    "TPU v5 lite": HW(
+        peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9, dci_bw=25e9,
+        hbm_bytes=16e9,
+    ),
+}
+
+
+def peaks_for(device_kind: str) -> HW:
+    """The peaks of ``device_kind``; an unknown kind is an error, never
+    a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks recorded for device kind {device_kind!r} "
+            f"(known: {', '.join(sorted(PEAKS))})"
+        ) from None
 
 
 _DTYPE_BYTES = {
@@ -166,7 +193,7 @@ def model_flops(cfg, shape) -> float:
     return 2.0 * n_active * tokens
 
 
-def analyze_compiled(compiled, *, n_devices: int, hw: HW = HW()) -> dict:
+def analyze_compiled(compiled, *, n_devices: int) -> dict:
     """Extract flops / bytes / collective wire bytes from a compiled
     executable.  cost_analysis flops are whole-program (all devices)."""
     cost = compiled.cost_analysis()
@@ -202,7 +229,7 @@ def analyze_compiled(compiled, *, n_devices: int, hw: HW = HW()) -> dict:
     }
 
 
-def roofline_terms(analysis: dict, *, n_devices: int, hw: HW = HW()) -> dict:
+def roofline_terms(analysis: dict, *, n_devices: int, hw: HW) -> dict:
     """The three terms in seconds + the dominant bottleneck.
 
     ``cost_analysis()`` on the compiled artifact reports the PER-PARTITION

@@ -14,10 +14,7 @@ _SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # jax <= 0.4.x
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.comm.collectives import (
         ring_all_gather, ring_reduce_scatter, ag_matmul, matmul_rs,
         halo_exchange, stencil_1d_sharded, jacobi_step_sharded,
@@ -25,10 +22,7 @@ _SCRIPT = textwrap.dedent(
 
     mesh = jax.make_mesh((8,), ("x",))
     def smap(f, in_specs, out_specs):
-        try:
-            return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-        except TypeError:  # jax <= 0.4.x spells it check_rep
-            return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+        return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
 
     k = jax.random.PRNGKey(0)
     # ring all-gather == lax.all_gather

@@ -12,15 +12,8 @@ from repro.launch.sharding import batch_specs, param_specs, state_specs
 from repro.launch.steps import cell_config, skip_reason
 from repro.models import init_params, make_decode_state
 
-def _abstract_mesh(sizes, names):
-    try:  # jax >= 0.5: AbstractMesh(axis_sizes, axis_names)
-        return AbstractMesh(sizes, names)
-    except TypeError:  # jax <= 0.4.x: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
-MESH = _abstract_mesh((16, 16), ("data", "model"))
-MESH3 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _leaf_specs(cfg, mesh=MESH):

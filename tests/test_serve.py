@@ -184,15 +184,19 @@ def test_admission_release_never_lost_with_two_queued_waiters():
         time.sleep(0.02 + round_ * 0.002)
         adm.release()
         ta.join(5.0)
+        assert not ta.is_alive(), (round_, results)
+        if results["timed"] == "admitted":
+            # the release landed before the deadline (a loaded machine
+            # starts the timed waiter late): it took the slot, so the
+            # notify was used, not lost; its request finishes now
+            adm.release()
         tb.join(10.0)
         assert not tb.is_alive(), (
             f"round {round_}: patient waiter stranded — release notify "
             f"was lost ({results})"
         )
-        # exactly one waiter got the freed slot; the other either also
-        # admitted (never possible here: one slot) or timed out
-        admitted = [k for k, v in results.items() if v == "admitted"]
-        assert len(admitted) == 1, (round_, results)
+        assert results["patient"] == "admitted", (round_, results)
+        assert results["timed"] in ("admitted", "timeout"), (round_, results)
         assert adm.inflight == 1
 
 
