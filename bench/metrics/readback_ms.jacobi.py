@@ -1,0 +1,19 @@
+"""Worker time reading payload results back per sweep: the seconds of
+the runtime's ``repro.exec.readback`` spans (the wait for the device,
+the download, the store into the host block) in the traced window,
+summed over the worker threads, in ms.
+
+The runtime sums the spans it puts in a profiler's trace
+(``repro.obs.profile_totals()``), and the traced window is the
+profiler's session; a runtime without that sum gives nothing.
+"""
+import sys
+
+
+def read(run):
+    obs = sys.modules.get("repro.obs")
+    spans = getattr(obs, "profile_totals", dict)().get("spans", {})
+    sweeps = run["counters"].get("sweeps")
+    if not sweeps or "exec.readback" not in spans:
+        return None
+    return 1e3 * spans["exec.readback"][1] / sweeps

@@ -694,10 +694,11 @@ class Runtime:
             from .plan_cache import PlanCache
 
             self._plan_cache = PlanCache()
-        # guards plan_stats / verify_stats / last_verify_report: with the
-        # plan stage off the record lock, several submitting threads
-        # plan (and verify) concurrently
+        # guards plan_stats / verify_stats / last_verify_report and the
+        # scan total: with the plan stage off the record lock, several
+        # submitting threads plan (and verify) concurrently
         self._stats_lock = threading.Lock()
+        self._scan_steps = 0  # of dependency systems retired so far
         # guards lazy executor/backend/channel construction (first
         # concurrent submit_cone calls race to build them)
         self._exec_lock = threading.Lock()
@@ -1221,6 +1222,7 @@ class Runtime:
             self._barrier_cleanup()
             return None if wait else FlushTicket(self)
         self.deps = DependencySystem()  # recording continues here
+        self._count_scans(deps)
         fid = self.flush_count + 1
         col = _obs.CURRENT
         if col is not None:
@@ -1228,41 +1230,15 @@ class Runtime:
                 fid, n_total, deps.n_pending, self.sync_mode, self.flush_backend
             )
             col.counter("cone-ops", deps.n_pending)
-        hints = {}
-        if self.passes:
-            from .plan import plan as run_plan
-
-            pre_views = None
-            if self.verify_mode != "off":
-                # snapshot footprints BEFORE planning: passes rewrite
-                # payloads/accesses in place (fill→map const folding), so
-                # the pre-plan op objects are not a record of the pre-plan
-                # program — immutable OpViews are
-                from repro.analysis import snapshot_ops
-
-                _t0 = _time.perf_counter()
-                pre_views = snapshot_ops(deps.pending_ops())
-                with self._stats_lock:
-                    self.verify_stats.verify_seconds += (
-                        _time.perf_counter() - _t0
-                    )
-            planned = run_plan(
-                deps,
-                self.passes,
-                dead_bases=dead,
-                storage=self.storage,
-            )
-            deps = planned.deps
-            hints = planned.hints
-            with self._stats_lock:
-                self.plan_stats.merge(planned.stats)
-            if pre_views is not None:
-                self._verify_plan(pre_views, planned, dead)
-        self.flush_count += 1
-        self._recorded_since_flush = self.deps.n_pending
-        if self.flush_backend == "async":
-            ticket = self._flush_async(deps, hints, fid, keys=None,
-                                       regions=None)
+        ticket = None
+        with _obs.span("plan", fid):
+            deps, hints = self._plan_graph(deps, dead)
+            self.flush_count += 1
+            self._recorded_since_flush = self.deps.n_pending
+            if self.flush_backend == "async":
+                ticket = self._flush_async(deps, hints, fid, keys=None,
+                                           regions=None)
+        if ticket is not None:
             if wait:
                 res = ticket.wait()
                 self._barrier_cleanup()
@@ -1285,6 +1261,56 @@ class Runtime:
         self._barrier_cleanup()
         return res if wait else FlushTicket(self, stats=res)
 
+    def _plan_graph(self, deps, dead):
+        """Plan stage of a whole-graph flush: run the pass pipeline (and
+        the verifier, where on) over ``deps``; returns the planned
+        ``(deps, hints)``."""
+        if not self.passes:
+            return deps, {}
+        from .plan import plan as run_plan
+
+        pre_views = None
+        if self.verify_mode != "off":
+            # snapshot footprints BEFORE planning: passes rewrite
+            # payloads/accesses in place (fill→map const folding), so
+            # the pre-plan op objects are not a record of the pre-plan
+            # program — immutable OpViews are
+            from repro.analysis import snapshot_ops
+
+            _t0 = _time.perf_counter()
+            pre_views = snapshot_ops(deps.pending_ops())
+            with self._stats_lock:
+                self.verify_stats.verify_seconds += (
+                    _time.perf_counter() - _t0
+                )
+        planned = run_plan(
+            deps,
+            self.passes,
+            dead_bases=dead,
+            storage=self.storage,
+        )
+        if planned.deps is not deps:
+            self._count_scans(planned.deps)
+        with self._stats_lock:
+            self.plan_stats.merge(planned.stats)
+        if pre_views is not None:
+            self._verify_plan(pre_views, planned, dead)
+        return planned.deps, planned.hints
+
+    def _count_scans(self, deps) -> None:
+        """Fold a dependency system that takes no more inserts into the
+        runtime's dependency-list scan total (:attr:`scan_steps`)."""
+        with self._stats_lock:
+            self._scan_steps += deps.scan_steps
+
+    @property
+    def scan_steps(self) -> int:
+        """Dependency-list entries scanned by every insert so far —
+        recording, and the re-insertions of cone extraction and
+        planning — over every dependency system this runtime built."""
+        with self._stats_lock:
+            return self._scan_steps + self.deps.scan_steps
+
     # -- the record/plan split (cone flushes) -------------------------------
     def extract_cone(self, targets) -> PendingFlush:
         """Record-side half of a cone flush: split the recorded graph
@@ -1300,6 +1326,10 @@ class Runtime:
         :meth:`submit_cone` plans and submits the cone."""
         if self._closed:
             raise RuntimeError("Runtime is closed")
+        with _obs.span("plan"):
+            return self._extract_cone(targets)
+
+    def _extract_cone(self, targets) -> PendingFlush:
         from .graph import cone_access_keys
 
         self._reap_tickets()  # fold finished drains' stats, keep going
@@ -1346,8 +1376,10 @@ class Runtime:
         # dead temp's producer (pulled in as an anti-dependency) while
         # its consumer stays pending — that store is NOT dead yet
         dead -= {acc.key[0] for op in rest_ops for acc in op.accesses}
+        self._count_scans(self.deps)
         self.deps = DependencySystem.rebuild(rest_ops)
         cone_deps = DependencySystem.rebuild(cone_ops)
+        self._count_scans(cone_deps)
         self.flush_count += 1
         fid = self.flush_count
         self._recorded_since_flush = self.deps.n_pending
@@ -1411,19 +1443,20 @@ class Runtime:
         # (verify="full"'s race oracle already ran in extract_cone,
         # under the record serialization that defines "in-flight")
         self._join_conflicting(handle.keys, before=ticket)
-        deps, hints = self._plan_cone(handle)
-        if self.flush_backend == "async":
-            if self._batcher is not None:
-                self._batcher.enqueue(deps, hints, ticket)
-            else:
-                executor = self._ensure_executor()
-                fut = executor.submit(
-                    deps,
-                    batch_dispatch=bool(hints.get("batch_dispatch")),
-                    tag=handle.fid,
-                )
-                ticket._bind(fut)
-            return
+        with _obs.span("plan", handle.fid):
+            deps, hints = self._plan_cone(handle)
+            if self.flush_backend == "async":
+                if self._batcher is not None:
+                    self._batcher.enqueue(deps, hints, ticket)
+                else:
+                    executor = self._ensure_executor()
+                    fut = executor.submit(
+                        deps,
+                        batch_dispatch=bool(hints.get("batch_dispatch")),
+                        tag=handle.fid,
+                    )
+                    ticket._bind(fut)
+                return
         # simulated backend (sync="demand" with flush_backend="sim"):
         # the drain runs inline on this thread, as before the split
         from repro.api.registry import get_scheduler
@@ -1471,6 +1504,8 @@ class Runtime:
                     new_deps, hints, stats = cache.replay(
                         entry, deps, pending
                     )
+                    if new_deps is not deps:
+                        self._count_scans(new_deps)
                     with self._stats_lock:
                         self.plan_stats.merge(stats)
                     return new_deps, hints
@@ -1505,6 +1540,8 @@ class Runtime:
         planned = run_plan(
             deps, self.passes, dead_bases=handle.dead, storage=self.storage
         )
+        if planned.deps is not deps:
+            self._count_scans(planned.deps)
         with self._stats_lock:
             self.plan_stats.merge(planned.stats)
         if self.verify_mode != "off":
@@ -1866,10 +1903,21 @@ class Runtime:
     # -- reporting -------------------------------------------------------------
     def backend_stats(self) -> dict:
         """The async compute backend's per-path payload counters (see
-        ``JaxBackend.stats``); empty before the first async drain and
-        after :meth:`close`."""
+        ``JaxBackend.stats``) and the runtime's dependency-list
+        :attr:`scan_steps`; empty before the first async drain and after
+        :meth:`close`."""
         backend = self._exec_backend_obj
-        return {} if backend is None else backend.stats()
+        if backend is None:
+            return {}
+        return dict(backend.stats(), scan_steps=self.scan_steps)
+
+    def span_totals(self) -> dict:
+        """``{stage: (count, wall seconds)}`` of the runtime's stage
+        spans (``record.insert``, ``plan``, ``exec.*``,
+        ``channel.transfer``), summed over threads; empty when no trace
+        collector is active."""
+        col = _obs.CURRENT
+        return {} if col is None else col.span_totals()
 
     def stats(self):
         """Accumulated run statistics: the simulated

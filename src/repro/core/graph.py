@@ -319,12 +319,26 @@ class DependencySystem:
     def insert(self, op: OperationNode) -> None:
         """Record ``op``: insert each access into its block's dependency
         list, accumulating the refcount from conflicting earlier accesses."""
+        steps = self.scan_steps
+        if self._replay:  # a replay runs inside the plan stage's span
+            refs = self._insert(op)
+        else:
+            with _obs.span("record.insert"):
+                refs = self._insert(op)
+            col = _obs.CURRENT
+            if col is not None:
+                col.op_recorded(op)
+        _obs.count("scan_steps", self.scan_steps - steps)
+        if refs == 0:
+            self._make_ready(op)
+
+    def _insert(self, op: OperationNode) -> int:
         op.seq = self.n_ops  # program order within THIS system
         refs = 0
         for acc in op.accesses:
             lst = self._lists.setdefault(acc.key, [])
+            self.scan_steps += len(lst)
             for prev in lst:
-                self.scan_steps += 1
                 if not prev.removed and prev.op is not op and prev.conflicts(acc):
                     prev.dependents.append(acc)
                     refs += 1
@@ -332,11 +346,7 @@ class DependencySystem:
         op.refcount = refs
         self.n_ops += 1
         self.n_pending += 1
-        col = _obs.CURRENT
-        if col is not None and not self._replay:
-            col.op_recorded(op)
-        if refs == 0:
-            self._make_ready(op)
+        return refs
 
     # -- execution bookkeeping -------------------------------------------
     def complete(self, op: OperationNode) -> list[OperationNode]:

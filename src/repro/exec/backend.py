@@ -33,6 +33,7 @@ two-sided rendezvous messaging.
 from __future__ import annotations
 
 import itertools
+import re
 import threading
 import time
 from typing import Optional
@@ -109,7 +110,18 @@ class JaxBackend(ComputeBackend):
       interpreter — those are memory movement, not FLOPs.
 
     :meth:`stats` counts payloads per path, including map payloads that
-    had to run on the host because a ufunc in them has no ``jnp`` form.
+    had to run on the host because a ufunc in them has no ``jnp`` form,
+    and the bytes each payload stages to the device and reads back.
+    Jitted payloads are named after their kind and ufunc
+    (``repro_map_<ufunc>``, ``repro_matmul``), so a device trace names
+    them.
+
+    With a trace collector active or a JAX profiler recording, each
+    payload runs under stage spans:
+    ``exec.stage`` (resolve, contiguous copy, upload), ``exec.launch``
+    (the jitted or Pallas call), ``exec.readback`` (the download and the
+    store into the host block), and ``exec.host`` for payloads that run
+    on NumPy.
 
     Note: without ``jax_enable_x64`` the payloads compute in float32, so
     results are *numerically close*, not bit-identical, to the NumPy
@@ -130,7 +142,6 @@ class JaxBackend(ComputeBackend):
         enable_compile_cache()
         self._jax = jax
         self._jnp = jnp
-        self._x64 = bool(jax.config.read("jax_enable_x64"))
         self._impls = {
             "identity": lambda x: x,
             "add": jnp.add,
@@ -164,27 +175,31 @@ class JaxBackend(ComputeBackend):
             n_host_untranslated=0,  # maps with no jnp form, run by NumPy
             n_host=0,  # reductions, fills: NumPy by design (transfers
                        # run on the channel, not here)
+            h2d_bytes=0,  # staged to the device by jitted/Pallas payloads
+            d2h_bytes=0,  # read back from the device into host blocks
         )
 
     # -- helpers ---------------------------------------------------------
-    def _count(self, key: str) -> None:
+    def _count(self, key: str, h2d: int = 0, d2h: int = 0) -> None:
         with self._count_lock:
-            self._counts[key] += 1
+            c = self._counts
+            c[key] += 1
+            c["h2d_bytes"] += h2d
+            c["d2h_bytes"] += d2h
+        _obs.count("h2d_bytes", h2d)  # a profiler session's share
+        _obs.count("d2h_bytes", d2h)
 
     def stats(self) -> dict:
-        """Payload counts per execution path, plus whether the Pallas
-        kernel runs interpreted (only on the CPU platform)."""
+        """Payload counts per execution path, host↔device bytes, and
+        whether the Pallas kernel runs interpreted (only on the CPU
+        platform)."""
         with self._count_lock:
             return dict(self._counts, interpret=self.interpret)
 
     def _to_device(self, x):
-        jnp = self._jnp
-        if isinstance(x, np.ndarray) and not self._x64:
-            if x.dtype == np.float64:
-                return jnp.asarray(x, dtype=jnp.float32)
-            if x.dtype == np.int64:
-                return jnp.asarray(x, dtype=jnp.int32)
-        return jnp.asarray(x)
+        # without x64, device_put narrows float64/int64 to 32 bits on the
+        # host as it uploads: no convert program runs on the device
+        return self._jax.device_put(x)
 
     def _impl_of(self, u) -> Optional[object]:
         return self._impls.get(u.name)
@@ -216,9 +231,19 @@ class JaxBackend(ComputeBackend):
             walk(ufunc.tree)
             if missing:
                 return None
-            return lambda *arrays: eval_tree(ufunc.tree, arrays, self._impl_of)
-        f = self._impl_of(ufunc)
-        return None if f is None else (lambda *arrays: f(*arrays))
+
+            def fn(*arrays):
+                return eval_tree(ufunc.tree, arrays, self._impl_of)
+        else:
+            f = self._impl_of(ufunc)
+            if f is None:
+                return None
+
+            def fn(*arrays):
+                return f(*arrays)
+        # the device program's name: jit_repro_map_<ufunc>
+        fn.__name__ = "repro_map_" + re.sub(r"\W+", "_", ufunc.name).strip("_")
+        return fn
 
     @staticmethod
     def _stencil5_weight(tree) -> Optional[float]:
@@ -250,27 +275,44 @@ class JaxBackend(ComputeBackend):
     # -- execution -------------------------------------------------------
     def execute(self, op: OperationNode) -> None:
         p = op.payload
+        fid = _flush_of(op)
         if isinstance(p, MapPayload):
-            if self._exec_map(p):
+            if self._exec_map(p, fid):
                 return
-            self._count("n_host_untranslated")
+            key = "n_host_untranslated"
         elif isinstance(p, MatmulPayload):
-            self._exec_matmul(p)
-            self._count("n_jit")
+            self._exec_matmul(p, fid)
             return
         else:
-            self._count("n_host")
-        execute_payload(p, self.storage, self.scratch)
+            key = "n_host"
+        with _obs.span("exec.host", fid):
+            execute_payload(p, self.storage, self.scratch)
+        self._count(key)
 
-    def _exec_map(self, p: MapPayload) -> bool:
-        ukey = (p.ufunc.name, self._tree_key(p.ufunc.tree))
-        if ukey in self._untranslatable:
-            return False  # known fallback: skip resolving refs twice
-        args = [resolve_ref(r, self.storage, self.scratch) for r in p.args]
-        arr_idx = [i for i, r in enumerate(p.args) if r[0] != "c"]
-        dev_args = list(args)
-        for i in arr_idx:
-            dev_args[i] = self._to_device(np.ascontiguousarray(args[i]))
+    def _exec_map(self, p: MapPayload, fid) -> bool:
+        with _obs.span("exec.stage", fid):
+            ukey = (p.ufunc.name, self._tree_key(p.ufunc.tree))
+            if ukey in self._untranslatable:
+                return False  # known fallback: skip resolving refs twice
+            args = [resolve_ref(r, self.storage, self.scratch) for r in p.args]
+            arr_idx = [i for i, r in enumerate(p.args) if r[0] != "c"]
+            dev_args = list(args)
+            for i in arr_idx:
+                dev_args[i] = self._to_device(np.ascontiguousarray(args[i]))
+        with _obs.span("exec.launch", fid):
+            key, res = self._launch_map(p, args, arr_idx, dev_args)
+        if res is None:
+            self._untranslatable.add(ukey)
+            return False
+        with _obs.span("exec.readback", fid):
+            out = np.asarray(res)
+            self._store(p, out)
+        self._count(key, sum(dev_args[i].nbytes for i in arr_idx), out.nbytes)
+        return True
+
+    def _launch_map(self, p: MapPayload, args, arr_idx, dev_args):
+        """Start a map payload on the device: ``(counter key, result)``,
+        or ``(None, None)`` when a ufunc in it has no ``jnp`` form."""
         # Pallas fast path: fused 5-point stencil block sweep (32-bit or
         # narrower only: Mosaic has no 64-bit types)
         if (
@@ -283,17 +325,12 @@ class JaxBackend(ComputeBackend):
             w = self._stencil5_weight(p.ufunc.tree)
             if w is not None:
                 xs = [dev_args[i] for i in arr_idx]
-                res = self._stencil5(*xs, weight=w, interpret=self.interpret)
-                self._store(p, np.asarray(res))
-                self._count("n_pallas")
-                return True
+                return "n_pallas", self._stencil5(*xs, weight=w,
+                                                  interpret=self.interpret)
         fn = self._cached_jit(p, args, arr_idx)
         if fn is None:
-            self._untranslatable.add(ukey)
-            return False
-        self._store(p, np.asarray(fn(*dev_args)))
-        self._count("n_jit")
-        return True
+            return None, None
+        return "n_jit", fn(*dev_args)
 
     @staticmethod
     def _tree_key(spec):
@@ -322,30 +359,50 @@ class JaxBackend(ComputeBackend):
             self._jit_cache[key] = fn
         return fn
 
-    def _exec_matmul(self, p: MatmulPayload) -> None:
-        jnp = self._jnp
-        a = resolve_ref(p.a, self.storage, self.scratch)
-        b = resolve_ref(p.b, self.storage, self.scratch)
-        if p.trans_a:
-            a = a.T
-        if p.trans_b:
-            b = b.T
-        key = ("mm", a.shape, b.shape, str(a.dtype), str(b.dtype))
-        fn = self._jit_cache.get(key)
-        if fn is None:
-            fn = self._jax.jit(lambda x, y: jnp.dot(x, y))
-            self._jit_cache[key] = fn
-        val = np.asarray(fn(self._to_device(np.ascontiguousarray(a)),
-                            self._to_device(np.ascontiguousarray(b))))
-        blk = self.storage[(p.out_base, p.out_frag.block)]
-        if p.init:
-            blk[p.out_frag.slices] = val
-        else:
-            blk[p.out_frag.slices] += val
+    def _exec_matmul(self, p: MatmulPayload, fid) -> None:
+        with _obs.span("exec.stage", fid):
+            a = resolve_ref(p.a, self.storage, self.scratch)
+            b = resolve_ref(p.b, self.storage, self.scratch)
+            if p.trans_a:
+                a = a.T
+            if p.trans_b:
+                b = b.T
+            da = self._to_device(np.ascontiguousarray(a))
+            db = self._to_device(np.ascontiguousarray(b))
+        with _obs.span("exec.launch", fid):
+            key = ("mm", a.shape, b.shape, str(a.dtype), str(b.dtype))
+            fn = self._jit_cache.get(key)
+            if fn is None:
+                fn = self._jax.jit(_repro_matmul(self._jnp))
+                self._jit_cache[key] = fn
+            res = fn(da, db)
+        with _obs.span("exec.readback", fid):
+            val = np.asarray(res)
+            blk = self.storage[(p.out_base, p.out_frag.block)]
+            if p.init:
+                blk[p.out_frag.slices] = val
+            else:
+                blk[p.out_frag.slices] += val
+        self._count("n_jit", da.nbytes + db.nbytes, val.nbytes)
 
     def _store(self, p: MapPayload, res: np.ndarray) -> None:
         blk = self.storage[(p.out_base, p.out_frag.block)]
         blk[p.out_frag.slices] = res
+
+
+def _flush_of(op: OperationNode):
+    """The flush id of the drain ``op`` runs in, where it has one."""
+    drain = getattr(op, "_drain", None)
+    return None if drain is None else drain.tag
+
+
+def _repro_matmul(jnp):
+    """The matmul payload's program, named ``jit_repro_matmul``."""
+
+    def repro_matmul(x, y):
+        return jnp.dot(x, y)
+
+    return repro_matmul
 
 
 class AutoBackend(ComputeBackend):
@@ -574,7 +631,9 @@ class AsyncExecutor:
 
     # -- transfer execution (runs on progress threads / workers) ----------
     def _exec_comm(self, op: OperationNode) -> None:
-        execute_payload(op.payload, self.backend.storage, self.backend.scratch)
+        with _obs.span("channel.transfer", _flush_of(op)):
+            execute_payload(op.payload, self.backend.storage,
+                            self.backend.scratch)
 
     # -- work stealing -----------------------------------------------------
     def _steal_for(self, thief: Worker) -> Optional[list[OperationNode]]:

@@ -8,7 +8,12 @@ pipeline.  Three pieces:
   enqueued / executed, message posted / progressed / delivered, worker
   wait spans tagged with *why*), installed globally via
   :func:`repro.trace`, ``ExecutionPolicy(trace=True)`` or
-  ``REPRO_TRACE=1``.  Disabled tracing is a true no-op.
+  ``REPRO_TRACE=1``.  Disabled tracing is a true no-op.  The
+  runtime's stage spans (:func:`span`) run under a collector or while a
+  JAX profiler records; under a profiler they are ``jax.profiler``
+  annotations named ``repro.*``, on the device trace's clock, and
+  :func:`profile_totals` sums them, and the bytes and dependency scans
+  counted meanwhile, for the latest profiler session.
 * :func:`export_trace` (:mod:`repro.obs.export`) — Chrome-trace /
   Perfetto JSON: one track per worker and per channel, flow arrows from
   each message's delivery to the compute op it unblocked, counter
@@ -34,6 +39,8 @@ from .collector import (
     activate,
     current_tracer,
     deactivate,
+    profile_totals,
+    span,
     trace,
 )
 from .export import export_trace, validate_trace
@@ -44,6 +51,8 @@ __all__ = [
     "activate",
     "deactivate",
     "current_tracer",
+    "span",
+    "profile_totals",
     "DEFAULT_CAPACITY",
     "export_trace",
     "validate_trace",

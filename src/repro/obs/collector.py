@@ -54,9 +54,22 @@ etype                   meaning / extra payload
 ``lock-held``           a serving lock was held; uid = lock label (e.g.
                         ``"record"``), extra = held seconds
 ======================  =====================================================
+
+Stage spans (:func:`span`) are not events.  Each runs where a
+collector is active or a JAX profiler records, and is a shared null
+context otherwise.  While a profiler records, a span opens a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>``, so the stage
+lands on the host plane of the trace on the device trace's clock, and
+adds its count and wall seconds to the profiler session's totals
+(:func:`profile_totals`), beside the counters the runtime bumps through
+:func:`count` meanwhile.  Under an active collector it adds them to the
+collector's totals (:meth:`TraceCollector.span_totals`).
 """
 from __future__ import annotations
 
+import contextlib
+import sys
+import threading
 import time
 from collections import deque
 from typing import Optional
@@ -69,6 +82,11 @@ __all__ = [
     "deactivate",
     "current_tracer",
     "trace",
+    "NO_SPAN",
+    "SpanTotals",
+    "span",
+    "count",
+    "profile_totals",
 ]
 
 DEFAULT_CAPACITY = 1_000_000
@@ -76,6 +94,175 @@ DEFAULT_CAPACITY = 1_000_000
 #: The active collector, or None (tracing disabled).  Instrumentation
 #: sites read this attribute directly; keep it a plain module global.
 CURRENT: Optional["TraceCollector"] = None
+
+#: What :func:`span` returns where neither a collector nor a profiler
+#: records: one shared null context.
+NO_SPAN = contextlib.nullcontext()
+
+_now = time.perf_counter
+
+# jax.profiler.TraceAnnotation once JAX is loaded; the collector never
+# imports JAX itself (a JAX profiler cannot be running without it)
+_annotation = None
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None:
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is not None:
+            _annotation = profiler.TraceAnnotation
+    return _annotation
+
+
+class SpanTotals:
+    """Per-thread ``{name: [count, seconds]}`` records, each written only
+    by its own thread (no lock on the hot path), merged when read."""
+
+    def __init__(self):
+        self._tls = threading.local()
+        self._threads: list = []  # (thread name, its records)
+        self._lock = threading.Lock()  # registration only
+
+    def record(self, name: str) -> list:
+        """This thread's ``[count, seconds]`` for ``name``."""
+        try:
+            recs = self._tls.recs
+        except AttributeError:
+            recs = self._tls.recs = {}
+            with self._lock:
+                self._threads.append((threading.current_thread().name, recs))
+        rec = recs.get(name)
+        if rec is None:
+            rec = recs[name] = [0, 0.0]
+        return rec
+
+    def by_thread(self) -> dict:
+        """``{thread name: {name: (count, seconds)}}``; threads that
+        share a name (one per runtime) are summed."""
+        with self._lock:
+            threads = list(self._threads)
+        out: dict = {}
+        for thread, recs in threads:
+            per = out.setdefault(thread, {})
+            for k, (n, s) in recs.copy().items():
+                n0, s0 = per.get(k, (0, 0.0))
+                per[k] = (n0 + n, s0 + s)
+        return out
+
+    def totals(self) -> dict:
+        """``{name: (count, seconds)}``, summed over threads."""
+        out: dict = {}
+        for per in self.by_thread().values():
+            for k, (n, s) in per.items():
+                n0, s0 = out.get(k, (0, 0.0))
+                out[k] = (n0 + n, s0 + s)
+        return out
+
+
+class _Session:
+    """What the runtime did while one profiler session recorded: its
+    spans, and its counters (a record's count is the amount)."""
+
+    def __init__(self):
+        self.t0 = _now()
+        self.spans = SpanTotals()
+        self.counters = SpanTotals()
+
+
+# totals of the latest profiler session: module state, as a process
+# runs one JAX profiler at a time
+_session: Optional[_Session] = None
+_session_lock = threading.Lock()
+_off_at = 0.0  # the latest time a site found no profiler recording
+
+
+def _profiled() -> Optional[_Session]:
+    """The totals of the profiler session recording now, or None.  A
+    session's totals start at the first site that finds it recording
+    after a site found none."""
+    global _session, _off_at
+    t = _now()  # before the test: a site that finds none began earlier
+    cls = _annotation or _trace_annotation()
+    if cls is None or not cls.is_enabled():
+        _off_at = t
+        return None
+    session = _session
+    if session is None or session.t0 < _off_at:
+        with _session_lock:
+            if _session is None or _session.t0 < _off_at:
+                _session = _Session()
+            session = _session
+    return session
+
+
+class _Span:
+    """One stage span: a profiler annotation and the session's record,
+    where a profiler records, and the collector's record, where one is
+    active."""
+
+    __slots__ = ("_rec", "_prof", "_ann", "_t0")
+
+    def __init__(self, rec: Optional[list], prof: Optional[list], ann):
+        self._rec = rec
+        self._prof = prof
+        self._ann = ann
+        self._t0 = 0.0
+
+    def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = _now()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        dt = _now() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        for rec in (self._rec, self._prof):
+            if rec is not None:
+                rec[0] += 1
+                rec[1] += dt
+        return False
+
+
+def span(name: str, flush_id=None):
+    """A span ``repro.<name>`` around one runtime stage (the flush id in
+    the annotation where given); :data:`NO_SPAN` where neither a
+    collector is active nor a profiler records."""
+    col = CURRENT
+    session = _profiled()
+    if session is None:
+        if col is None:
+            return NO_SPAN
+        return _Span(col.spans.record(name), None, None)
+    if flush_id is None:
+        ann = _annotation("repro." + name)
+    else:
+        ann = _annotation("repro." + name, flush_id=flush_id)
+    return _Span(None if col is None else col.spans.record(name),
+                 session.spans.record(name), ann)
+
+
+def count(name: str, n: int) -> None:
+    """Add ``n`` to the counter ``name`` of the profiler session that
+    records now; nothing where none records."""
+    if n:
+        session = _profiled()
+        if session is not None:
+            session.counters.record(name)[0] += n
+
+
+def profile_totals() -> dict:
+    """What the runtime did in the latest profiler session in which it
+    ran a stage: ``{"spans": {name: (count, seconds)}, "counters":
+    {name: amount}}``, or ``{}`` before any."""
+    session = _session
+    if session is None:
+        return {}
+    return {"spans": session.spans.totals(),
+            "counters": {k: n for k, (n, _) in
+                         session.counters.totals().items()}}
 
 
 class TraceCollector:
@@ -96,6 +283,7 @@ class TraceCollector:
         # route every op back to the drain segment that owns it
         self.flush_of: dict = {}
         self.n_emitted = 0
+        self.spans = SpanTotals()  # stage spans (:func:`span`)
 
     # -- introspection ----------------------------------------------------
     def __len__(self) -> int:
@@ -108,6 +296,15 @@ class TraceCollector:
 
     def now(self) -> float:
         return time.perf_counter() - self.t0
+
+    # -- stage spans -------------------------------------------------------
+    def span_totals_by_thread(self) -> dict:
+        """``{thread name: {span name: (count, seconds)}}``."""
+        return self.spans.by_thread()
+
+    def span_totals(self) -> dict:
+        """``{span name: (count, seconds)}``, summed over threads."""
+        return self.spans.totals()
 
     # -- recording / planning --------------------------------------------
     def op_recorded(self, op) -> None:
