@@ -58,6 +58,8 @@ from .ufunc import UFunc, get_ufunc, reduce_fn
 __all__ = [
     "Runtime",
     "ArrayBase",
+    "BlockStore",
+    "block_dtype",
     "FlushTicket",
     "PendingFlush",
     "current_runtime",
@@ -172,6 +174,46 @@ class FusedMapReducePayload:
 # that respects the dependency graph's ordering of conflicting accesses
 # produces bit-identical block contents through it.
 # ---------------------------------------------------------------------------
+
+
+class BlockStore(dict):
+    """The runtime's block storage: ``(base_id, coord) -> block``.
+
+    ``scatter`` and ``fill_base`` write host ``np.ndarray`` blocks.  A
+    device backend (``repro.exec.JaxBackend``) replaces a block with a
+    device array the first time a payload touches it, and from then on
+    the block lives on the device.  Two side tables serve that move:
+
+    * ``fills``: the value each ``fill_base`` block was filled with, so
+      a device backend creates such a block on the device instead of
+      uploading it;
+    * ``declared``: the program's dtype of each device block that
+      computes in a narrower one (float64 as float32 without x64), so
+      that the planner and the plan cache read the dtype they read
+      before the move (:func:`block_dtype`).
+    """
+
+    def __init__(self):
+        super().__init__()
+        self.fills: dict = {}
+        self.declared: dict = {}
+
+    def __delitem__(self, key) -> None:
+        super().__delitem__(key)
+        self.fills.pop(key, None)
+        self.declared.pop(key, None)
+
+
+def block_dtype(storage: dict, key) -> Optional[np.dtype]:
+    """The dtype the program declared for block ``key`` (None when the
+    block does not exist), wherever the block lives."""
+    blk = storage.get(key)
+    if blk is None:
+        return None
+    declared = getattr(storage, "declared", None)
+    if declared:
+        return declared.get(key, blk.dtype)
+    return blk.dtype
 
 
 def resolve_ref(ref, storage: dict, scratch: dict):
@@ -640,7 +682,7 @@ class Runtime:
         self._closed = False
 
         self.deps = DependencySystem()
-        self.storage: dict[tuple, np.ndarray] = {}  # (base_id, coord) -> block
+        self.storage = BlockStore()  # (base_id, coord) -> block
         self.scratch: dict[int, np.ndarray] = {}
         self._xfer_cache: dict[tuple, int] = {}
         self._write_epoch: dict[tuple, int] = {}  # (base_id, coord) -> version
@@ -863,9 +905,11 @@ class Runtime:
 
     def fill_base(self, base: ArrayBase, value) -> None:
         for coord, _ in base.layout.blocks():
-            self.storage[(base.id, coord)] = np.full(
+            key = (base.id, coord)
+            self.storage[key] = np.full(
                 base.layout.block_shape_at(coord), value, dtype=base.dtype
             )
+            self.storage.fills[key] = value
 
     def gather(self, base: ArrayBase, view: ViewSpec) -> np.ndarray:
         """Read back a view (flushes first — §5.6 trigger 1).
@@ -884,10 +928,11 @@ class Runtime:
             self.flush(targets=keys)
         else:
             self.flush()
-        out = np.empty(view.vshape, dtype=base.dtype)
-        for vint, (frag,) in fragment_iteration_space(view.vshape, (spec,)):
-            dst = tuple(slice(lo, hi) for lo, hi in vint)
-            blk = self.storage.get((base.id, frag.block))
+        frags = list(fragment_iteration_space(view.vshape, (spec,)))
+        blocks = {}
+        for _, (frag,) in frags:
+            key = (base.id, frag.block)
+            blk = self.storage.get(key)
             if blk is None:
                 raise RuntimeError(
                     f"array base {base.id} has no block storage — its blocks "
@@ -895,7 +940,18 @@ class Runtime:
                     f"collected; keep a reference to the DistArray (or its "
                     f"ArrayFuture) until readback"
                 )
-            out[dst] = blk[frag.slices]
+            blocks[key] = blk
+        on_device = [k for k, b in blocks.items() if not isinstance(b, np.ndarray)]
+        if on_device:
+            # the only device->host read: each touched block once
+            with _obs.span("exec.readback"):
+                host = self._exec_backend_obj.readback(
+                    [blocks[k] for k in on_device])
+            blocks.update(zip(on_device, host))
+        out = np.empty(view.vshape, dtype=base.dtype)
+        for vint, (frag,) in frags:
+            dst = tuple(slice(lo, hi) for lo, hi in vint)
+            out[dst] = blocks[(base.id, frag.block)][frag.slices]
         return out
 
     # -- recording ------------------------------------------------------------
@@ -1869,16 +1925,33 @@ class Runtime:
         cache, and combine-init state must survive partial flushes
         (remainder operations still reference scratch delivered by an
         earlier cone), so they are recycled only here; likewise block
-        storage of dead bases may still be read by pending operations."""
+        storage of dead bases may still be read by pending operations.
+        Short of a barrier, only what no pending operation and no
+        in-flight drain touches is recycled: a demand-driven program may
+        never reach a barrier, and under a device backend its dead
+        temporaries' blocks and its scratch would fill the device."""
         with self._ticket_lock:
-            if self._tickets:
-                return
-        if self.deps.n_pending:
+            inflight = list(self._tickets)
+        if inflight or self.deps.n_pending:
+            if any(t._keys is None for t in inflight):
+                return  # a whole-graph drain may touch anything
+            used = {acc.key for op in self.deps.pending_ops()
+                    for acc in op.accesses}
+            for t in inflight:
+                for keys in t._keys:
+                    used.update(keys)
+            self._purge_dead(self._dead_bases - {k[0] for k in used})
+            keep = {k[1] for k in used if k[0] == "s"}
+            gone = {sid for sid in list(self.scratch) if sid not in keep}
+            for sid in gone:
+                del self.scratch[sid]
+            self._xfer_cache = {k: sid for k, sid in self._xfer_cache.items()
+                                if sid not in gone}
             return
         self.scratch.clear()
         self._xfer_cache.clear()
         self._combine_seen.clear()
-        self._purge_dead()
+        self._purge_dead(set(self._dead_bases))
 
     def _ensure_exec_stats(self):
         if self.exec_stats is None:
@@ -1888,17 +1961,21 @@ class Runtime:
             self.exec_stats = WaitStats(mode=mode, nworkers=self.nprocs)
         return self.exec_stats
 
-    def _purge_dead(self) -> None:
-        if not self._dead_bases:
+    def _purge_dead(self, dead: set) -> None:
+        """Drop the blocks and bookkeeping of the dead bases ``dead``.
+        The set of dead bases is updated in place: every base's
+        finalizer adds to that one set."""
+        if not dead:
             return
-        dead = self._dead_bases
         for key in [k for k in self.storage if k[0] in dead]:
             del self.storage[key]
         for key in [k for k in self._write_epoch if k[0] in dead]:
             del self._write_epoch[key]
         for bid in dead:
             self._live_bases.pop(bid, None)
-        self._dead_bases = set()
+        self._combine_seen = {k for k in self._combine_seen
+                              if k[0] not in dead}
+        self._dead_bases.difference_update(dead)
 
     # -- reporting -------------------------------------------------------------
     def backend_stats(self) -> dict:

@@ -50,7 +50,7 @@ from typing import Iterable, Optional, Sequence, Union
 from repro.api.registry import get_pass, register_pass
 from repro.obs import collector as _obs
 
-from .engine import CoalescedTransferPayload, TransferPayload
+from .engine import CoalescedTransferPayload, TransferPayload, block_dtype
 from .graph import (
     COMM,
     AccessNode,
@@ -135,8 +135,7 @@ class PlanContext:
     _active_pass: Optional[str] = None
 
     def dtype_of(self, base_id: int, block: tuple):
-        blk = self.storage.get((base_id, block))
-        return None if blk is None else blk.dtype
+        return block_dtype(self.storage, (base_id, block))
 
     def note_rewrite(self, op: OperationNode, sources) -> None:
         """Record that the active pass built ``op`` out of ``sources``

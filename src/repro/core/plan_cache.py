@@ -53,6 +53,7 @@ from .engine import (
     MatmulPayload,
     ReducePartialPayload,
     TransferPayload,
+    block_dtype,
 )
 from .graph import COMM, COMPUTE, AccessNode, OperationNode
 
@@ -127,8 +128,8 @@ class _Uncacheable(Exception):
 
 
 def _block_dtype(storage, bid, block):
-    blk = storage.get((bid, block))
-    return None if blk is None else str(blk.dtype)
+    dtype = block_dtype(storage, (bid, block))
+    return None if dtype is None else str(dtype)
 
 
 def _ref_sig(ref, canon: _Canon, storage):

@@ -36,12 +36,22 @@ import itertools
 import re
 import threading
 import time
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
 from repro.api.registry import get_backend, register_backend
-from repro.core.engine import MapPayload, MatmulPayload, execute_payload, resolve_ref
+from repro.core.engine import (
+    CoalescedTransferPayload,
+    CombinePayload,
+    FillPayload,
+    FusedMapReducePayload,
+    MapPayload,
+    MatmulPayload,
+    ReducePartialPayload,
+    execute_payload,
+    resolve_ref,
+)
 from repro.core.graph import COMM, DependencySystem, OperationNode
 from repro.core.scheduler import DeadlockError, format_stuck_ops
 from repro.obs import collector as _obs
@@ -79,6 +89,12 @@ class ComputeBackend:
     def execute(self, op: OperationNode) -> None:
         raise NotImplementedError
 
+    def transfer(self, p) -> None:
+        """Run a transfer payload (on a channel's thread): the NumPy
+        interpreter's copy into scratch, unless a backend keeps its
+        blocks elsewhere."""
+        execute_payload(p, self.storage, self.scratch)
+
     def stats(self) -> dict:
         """Per-path payload counters (empty for a backend without any)."""
         return {}
@@ -94,34 +110,107 @@ class NumpyBackend(ComputeBackend):
         execute_payload(op.payload, self.storage, self.scratch)
 
 
+# a lock per block, striped over this many locks: a block's lock guards
+# its read-update-replace
+_LOCK_STRIPES = 256
+# the narrowest fragment the Pallas stencil kernel takes (one sublane
+# tile); narrower halo slivers take the jitted jnp path
+_STENCIL5_MIN_DIM = 8
+
+
+def _slices(local) -> tuple:
+    return tuple(slice(s, e, st) for s, e, st in local)
+
+
+def _cut(x, local):
+    """``x`` cut to a fragment's per-dim ``(start, stop, step)``; the
+    whole of ``x`` for None.  Runs while a program traces."""
+    return x if local is None else x[_slices(local)]
+
+
+def _cut_shape(shape, local):
+    if local is None:
+        return shape
+    return tuple(-(-(e - s) // st) for s, e, st in local)
+
+
+class _Sent(NamedTuple):
+    """A transfer's scratch under :class:`JaxBackend`: the source block
+    as it was sent (immutable) and the fragment's per-dim
+    ``(start, stop, step)``."""
+
+    block: object
+    local: tuple
+
+
+class _HostCopies(dict):
+    """Host copies of the blocks (or scratch buffers) of ``src`` that a
+    host fallback reads, each downloaded on its first access; the bytes
+    read from the device go to ``moved``."""
+
+    def __init__(self, src: dict, moved: list):
+        super().__init__()
+        self._src = src
+        self._moved = moved
+
+    def __missing__(self, key):
+        x = self._src[key]
+        if not isinstance(x, np.ndarray):
+            host = np.asarray(x.block if isinstance(x, _Sent) else x)
+            self._moved.append(host.nbytes)
+            x = host[_slices(x.local)] if isinstance(x, _Sent) else host
+        self[key] = x
+        return x
+
+
 class JaxBackend(ComputeBackend):
-    """jit-compiles block payloads with XLA.
+    """Runs every payload with XLA on device-resident blocks.
 
-    * Elementwise map payloads (including fused expression trees, via
-      ``UFunc.tree``) are retraced with ``jax.numpy`` primitives and
-      cached per (ufunc, signature).
-    * Fused 5-point stencil payloads of 32-bit (or narrower) dtype are
-      routed through the Pallas ``stencil5_block`` kernel from
-      ``repro.kernels.stencil`` (interpreted on the CPU platform,
-      compiled everywhere else).  Mosaic has no 64-bit types, so under
-      ``jax_enable_x64`` float64 stencils take the jitted ``jnp`` path.
-    * Matmul payloads run through a jitted ``jnp.dot``.
-    * Everything else (transfers, reductions, fills) runs on the NumPy
-      interpreter — those are memory movement, not FLOPs.
+    * **Blocks live on the device.**  The first payload that touches a
+      block holding a host ``np.ndarray`` uploads it once, in the dtype
+      the device computes in, and replaces the storage entry with the
+      device array; a block that ``fill_base`` made is created on the
+      device instead of uploaded.  Bytes cross to the host only where
+      the program reads values (:meth:`readback`, called by
+      ``Runtime.gather``) and where a payload has no device form.
+    * **One program per payload shape.**  Each payload is a jitted
+      program over whole blocks, cut to its fragments inside the
+      program.  Programs are cached by the payload's kind and ufunc and
+      each operand's shape, dtype and block-local slice — never by block
+      coordinate — so every block of one cut pattern shares a program.
+      Fused 5-point stencils of 32-bit (or narrower) dtype over
+      fragments of at least 8 rows and columns run the Pallas
+      ``stencil5_block`` kernel (interpreted on the CPU platform); halo
+      slivers, and float64 under ``jax_enable_x64`` (Mosaic has no
+      64-bit types), take the jitted ``jnp`` path.
+    * **One write point.**  A write is the functional update
+      ``storage[key] = blk.at[slices].set(value)``, a jitted program run
+      under the block's lock: two workers may write disjoint fragments
+      of one block at once.  :meth:`_store` is where a map payload's
+      result lands in its block.
+    * **No blocking, no copies in transit.**  Workers dispatch and
+      return; nothing waits on a result between a drain's payloads.  A
+      transfer (:meth:`transfer`, on the channel's threads) puts the
+      source block as it is at send time, with the fragment's slice,
+      into scratch: a ``jax.Array`` is immutable, so that is the
+      send-time snapshot, and the program that reads the scratch cuts
+      it.
+    * **Host fallback.**  A map with a ufunc that has no ``jnp`` form
+      downloads its inputs, runs NumPy, and uploads its result through
+      :meth:`_store`.
 
-    :meth:`stats` counts payloads per path, including map payloads that
-    had to run on the host because a ufunc in them has no ``jnp`` form,
-    and the bytes each payload stages to the device and reads back.
-    Jitted payloads are named after their kind and ufunc
-    (``repro_map_<ufunc>``, ``repro_matmul``), so a device trace names
-    them.
+    :meth:`stats` counts payloads per path, transfers, the payloads that
+    moved bytes between host and device (``n_staged``: fallbacks and
+    first-touch uploads) and those bytes, readbacks included.  Programs
+    are named after their kind (``repro_map_<ufunc>``,
+    ``repro_stencil5``, ``repro_update``, ``repro_matmul``, ...), so a
+    device trace names them.
 
     With a trace collector active or a JAX profiler recording, each
-    payload runs under stage spans:
-    ``exec.stage`` (resolve, contiguous copy, upload), ``exec.launch``
-    (the jitted or Pallas call), ``exec.readback`` (the download and the
-    store into the host block), and ``exec.host`` for payloads that run
-    on NumPy.
+    payload runs under stage spans: ``exec.stage`` (resolving refs into
+    the program's arguments, first-touch uploads included),
+    ``exec.launch`` (the program's dispatch and the block update), and
+    ``exec.host`` for a payload that runs on NumPy.
 
     Note: without ``jax_enable_x64`` the payloads compute in float32, so
     results are *numerically close*, not bit-identical, to the NumPy
@@ -163,43 +252,141 @@ class JaxBackend(ComputeBackend):
             "less": jnp.less,
             "where": jnp.where,
         }
-        self._jit_cache: dict = {}
-        self._untranslatable: set = set()  # (name, tree_key) with no jnp form
+        self._reductions = {
+            "add": jnp.sum,
+            "multiply": jnp.prod,
+            "maximum": jnp.max,
+            "minimum": jnp.min,
+        }
+        self._programs: dict = {}
         self._stencil5 = stencil5_block
         self.interpret = resolve_interpret(None)
+        self._locks = [threading.Lock() for _ in range(_LOCK_STRIPES)]
+        self._stages = {
+            MapPayload: self._stage_map,
+            FusedMapReducePayload: self._stage_fused,
+            ReducePartialPayload: self._stage_reduce,
+            CombinePayload: self._stage_combine,
+            FillPayload: self._stage_fill,
+            MatmulPayload: self._stage_matmul,
+        }
         # payload counters, bumped from every worker thread
         self._count_lock = threading.Lock()
         self._counts = dict(
-            n_jit=0,  # maps and matmuls run as jitted XLA programs
+            n_jit=0,  # payloads run as jitted XLA programs
             n_pallas=0,  # fused stencils run through the Pallas kernel
             n_host_untranslated=0,  # maps with no jnp form, run by NumPy
-            n_host=0,  # reductions, fills: NumPy by design (transfers
-                       # run on the channel, not here)
-            h2d_bytes=0,  # staged to the device by jitted/Pallas payloads
-            d2h_bytes=0,  # read back from the device into host blocks
+            n_transfer=0,  # transfers, run on the channel's threads
+            n_staged=0,  # payloads that moved bytes host<->device
+            h2d_bytes=0,  # first-touch uploads and host results
+            d2h_bytes=0,  # readbacks at gather and fallback inputs
         )
 
     # -- helpers ---------------------------------------------------------
-    def _count(self, key: str, h2d: int = 0, d2h: int = 0) -> None:
+    def _count(self, key: Optional[str] = None, h2d: int = 0, d2h: int = 0,
+               staged: bool = False) -> None:
         with self._count_lock:
             c = self._counts
-            c[key] += 1
+            if key is not None:
+                c[key] += 1
+            c["n_staged"] += staged
             c["h2d_bytes"] += h2d
             c["d2h_bytes"] += d2h
         _obs.count("h2d_bytes", h2d)  # a profiler session's share
         _obs.count("d2h_bytes", d2h)
 
     def stats(self) -> dict:
-        """Payload counts per execution path, host↔device bytes, and
-        whether the Pallas kernel runs interpreted (only on the CPU
-        platform)."""
+        """Payload counts per execution path, transfers, staged payloads,
+        host↔device bytes, and whether the Pallas kernel runs
+        interpreted (only on the CPU platform)."""
         with self._count_lock:
             return dict(self._counts, interpret=self.interpret)
 
-    def _to_device(self, x):
+    def _program(self, key, build):
+        """The program cached under ``key``, built on a miss (workers
+        that race to build one all take the first stored)."""
+        try:
+            return self._programs[key]
+        except KeyError:
+            return self._programs.setdefault(key, build())
+
+    def _upload(self, x: np.ndarray):
         # without x64, device_put narrows float64/int64 to 32 bits on the
         # host as it uploads: no convert program runs on the device
+        self._count(h2d=x.nbytes)
         return self._jax.device_put(x)
+
+    def _device_block(self, key, moved: list):
+        """Block ``key`` as a device array: a host block moves to the
+        device on first touch (the bytes it uploads go to ``moved``)."""
+        blk = self.storage[key]
+        if isinstance(blk, np.ndarray):
+            with self._locks[hash(key) % _LOCK_STRIPES]:
+                blk = self.storage[key]
+                if isinstance(blk, np.ndarray):
+                    blk = self._migrate(key, blk, moved)
+        return blk
+
+    def _migrate(self, key, host: np.ndarray, moved: list):
+        fills = getattr(self.storage, "fills", {})
+        if key in fills:
+            dtype = self._jax.dtypes.canonicalize_dtype(host.dtype)
+            full = self._program(
+                ("full", host.shape, dtype),
+                lambda: self._jax.jit(_repro_fill(self._jnp, host.shape, dtype)),
+            )
+            dev = full(fills.pop(key))
+        else:
+            dev = self._jax.device_put(host)
+            moved.append(host.nbytes)
+        declared = getattr(self.storage, "declared", None)
+        if declared is not None and dev.dtype != host.dtype:
+            declared[key] = host.dtype
+        self.storage[key] = dev
+        return dev
+
+    def _operand(self, ref, moved: list):
+        """A payload input as a program takes it, ``(value, signature)``:
+        a whole device block (cut inside the program), a scratch buffer,
+        or a constant (signature None)."""
+        kind = ref[0]
+        if kind == "b":
+            _, bid, frag = ref
+            blk = self._device_block((bid, frag.block), moved)
+            return blk, (blk.shape, blk.dtype, frag.local)
+        if kind == "s":
+            x = self.scratch[ref[1]]
+            if isinstance(x, _Sent):
+                return x.block, (x.block.shape, x.block.dtype, x.local)
+            return x, (x.shape, x.dtype, None)
+        return ref[1], None
+
+    def _write(self, key, local, value, combine: Optional[str] = None) -> None:
+        """Update a device block's fragment: ``blk.at[local].set(value)``,
+        or with ``combine(blk[local], value)``, under the block's lock."""
+        update = self._program(
+            ("update", local, combine),
+            lambda: self._jax.jit(_repro_update(
+                self._jnp, local,
+                None if combine is None else self._impls[combine])),
+        )
+        with self._locks[hash(key) % _LOCK_STRIPES]:
+            self.storage[key] = update(self.storage[key], value)
+
+    def _store(self, p: MapPayload, res) -> None:
+        """The one point where a map payload's result lands in its block
+        (already on the device): a device array, or a host array that is
+        uploaded first."""
+        if isinstance(res, np.ndarray):
+            res = self._upload(res)
+        self._write((p.out_base, p.out_frag.block), p.out_frag.local, res)
+
+    def readback(self, blocks: list) -> list:
+        """Host copies of device blocks, each read once: the runtime's
+        one device→host read (``Runtime.gather``)."""
+        host = self._jax.device_get(blocks)
+        self._count(d2h=sum(x.nbytes for x in host))
+        return host
 
     def _impl_of(self, u) -> Optional[object]:
         return self._impls.get(u.name)
@@ -272,66 +459,6 @@ class JaxBackend(ComputeBackend):
             return None
         return float(const[1])
 
-    # -- execution -------------------------------------------------------
-    def execute(self, op: OperationNode) -> None:
-        p = op.payload
-        fid = _flush_of(op)
-        if isinstance(p, MapPayload):
-            if self._exec_map(p, fid):
-                return
-            key = "n_host_untranslated"
-        elif isinstance(p, MatmulPayload):
-            self._exec_matmul(p, fid)
-            return
-        else:
-            key = "n_host"
-        with _obs.span("exec.host", fid):
-            execute_payload(p, self.storage, self.scratch)
-        self._count(key)
-
-    def _exec_map(self, p: MapPayload, fid) -> bool:
-        with _obs.span("exec.stage", fid):
-            ukey = (p.ufunc.name, self._tree_key(p.ufunc.tree))
-            if ukey in self._untranslatable:
-                return False  # known fallback: skip resolving refs twice
-            args = [resolve_ref(r, self.storage, self.scratch) for r in p.args]
-            arr_idx = [i for i, r in enumerate(p.args) if r[0] != "c"]
-            dev_args = list(args)
-            for i in arr_idx:
-                dev_args[i] = self._to_device(np.ascontiguousarray(args[i]))
-        with _obs.span("exec.launch", fid):
-            key, res = self._launch_map(p, args, arr_idx, dev_args)
-        if res is None:
-            self._untranslatable.add(ukey)
-            return False
-        with _obs.span("exec.readback", fid):
-            out = np.asarray(res)
-            self._store(p, out)
-        self._count(key, sum(dev_args[i].nbytes for i in arr_idx), out.nbytes)
-        return True
-
-    def _launch_map(self, p: MapPayload, args, arr_idx, dev_args):
-        """Start a map payload on the device: ``(counter key, result)``,
-        or ``(None, None)`` when a ufunc in it has no ``jnp`` form."""
-        # Pallas fast path: fused 5-point stencil block sweep (32-bit or
-        # narrower only: Mosaic has no 64-bit types)
-        if (
-            p.ufunc.tree is not None
-            and len(arr_idx) == 5
-            and all(dev_args[i].ndim == 2 for i in arr_idx)
-            and len({dev_args[i].shape for i in arr_idx}) == 1
-            and all(dev_args[i].dtype.itemsize <= 4 for i in arr_idx)
-        ):
-            w = self._stencil5_weight(p.ufunc.tree)
-            if w is not None:
-                xs = [dev_args[i] for i in arr_idx]
-                return "n_pallas", self._stencil5(*xs, weight=w,
-                                                  interpret=self.interpret)
-        fn = self._cached_jit(p, args, arr_idx)
-        if fn is None:
-            return None, None
-        return "n_jit", fn(*dev_args)
-
     @staticmethod
     def _tree_key(spec):
         """Structural signature of an expression tree: two independently
@@ -346,48 +473,178 @@ class JaxBackend(ComputeBackend):
         f, subs = spec
         return (f.name, tuple(JaxBackend._tree_key(s) for s in subs))
 
-    def _cached_jit(self, p: MapPayload, args, arr_idx):
-        sig = tuple(
-            (args[i].shape, str(args[i].dtype)) if i in arr_idx else ("c",)
-            for i in range(len(args))
+    def _jnp_of(self, ufunc):
+        """``(structural key, jnp callable or None)`` of a ufunc."""
+        ukey = (ufunc.name, self._tree_key(ufunc.tree))
+        return ukey, self._program(("jnp",) + ukey,
+                                   lambda: self._trace_ufunc(ufunc))
+
+    def _map_program(self, ufunc, sig, ukey, traced):
+        """``(counter key, program)`` of a map over operands of ``sig``
+        (one ``(shape, dtype, local slice or None)`` per array, None per
+        constant), given the ufunc's :meth:`_jnp_of`."""
+        return self._program(("map",) + ukey + (sig,),
+                             lambda: self._build_map(ufunc, traced, sig))
+
+    def _build_map(self, ufunc, traced, sig):
+        cuts = [None if s is None else s[2] for s in sig]
+        w = self._stencil5_weight(ufunc.tree)
+        shapes = {_cut_shape(s[0], s[2]) for s in sig if s is not None}
+        if (
+            w is not None
+            and len(sig) == 5
+            and None not in sig
+            and len(shapes) == 1
+            and len(next(iter(shapes))) == 2
+            # a halo sliver (under 8 rows or columns) fills no kernel
+            # tile: XLA's fusion does as well, and compiles faster
+            and min(next(iter(shapes))) >= _STENCIL5_MIN_DIM
+            and all(s[1].itemsize <= 4 for s in sig)
+        ):
+            stencil5, interpret = self._stencil5, self.interpret
+
+            def repro_stencil5(*blocks):
+                xs = [_cut(b, c) for b, c in zip(blocks, cuts)]
+                return stencil5(*xs, weight=w, interpret=interpret)
+
+            return "n_pallas", self._jax.jit(repro_stencil5)
+
+        def prog(*args):
+            return traced(*[_cut(a, c) for a, c in zip(args, cuts)])
+
+        prog.__name__ = traced.__name__
+        return "n_jit", self._jax.jit(prog)
+
+    def _matmul_program(self, local_a, local_b, trans_a, trans_b):
+        return self._program(
+            ("matmul", local_a, local_b, trans_a, trans_b),
+            lambda: self._jax.jit(_repro_matmul(self._jnp, local_a, local_b,
+                                                trans_a, trans_b)),
         )
-        key = (p.ufunc.name, self._tree_key(p.ufunc.tree), sig)
-        fn = self._jit_cache.get(key)
-        if fn is None and key not in self._jit_cache:
-            traced = self._trace_ufunc(p.ufunc)
-            fn = None if traced is None else self._jax.jit(traced)
-            self._jit_cache[key] = fn
-        return fn
 
-    def _exec_matmul(self, p: MatmulPayload, fid) -> None:
+    # -- execution -------------------------------------------------------
+    def execute(self, op: OperationNode) -> None:
+        p = op.payload
+        fid = _flush_of(op)
+        moved: list = []
         with _obs.span("exec.stage", fid):
-            a = resolve_ref(p.a, self.storage, self.scratch)
-            b = resolve_ref(p.b, self.storage, self.scratch)
-            if p.trans_a:
-                a = a.T
-            if p.trans_b:
-                b = b.T
-            da = self._to_device(np.ascontiguousarray(a))
-            db = self._to_device(np.ascontiguousarray(b))
+            key, launch = self._stages[type(p)](p, moved)
+        if launch is None:  # a ufunc in it has no jnp form
+            self._exec_host(p, fid, moved)
+            return
         with _obs.span("exec.launch", fid):
-            key = ("mm", a.shape, b.shape, str(a.dtype), str(b.dtype))
-            fn = self._jit_cache.get(key)
-            if fn is None:
-                fn = self._jax.jit(_repro_matmul(self._jnp))
-                self._jit_cache[key] = fn
-            res = fn(da, db)
-        with _obs.span("exec.readback", fid):
-            val = np.asarray(res)
-            blk = self.storage[(p.out_base, p.out_frag.block)]
-            if p.init:
-                blk[p.out_frag.slices] = val
-            else:
-                blk[p.out_frag.slices] += val
-        self._count("n_jit", da.nbytes + db.nbytes, val.nbytes)
+            launch()
+            self._count(key, h2d=sum(moved), staged=bool(moved))
 
-    def _store(self, p: MapPayload, res: np.ndarray) -> None:
-        blk = self.storage[(p.out_base, p.out_frag.block)]
-        blk[p.out_frag.slices] = res
+    # Each stage resolves a payload's refs into its program's arguments
+    # and returns ``(counter key, launch)``; ``launch`` dispatches the
+    # program and lands its result.
+
+    def _stage_map(self, p: MapPayload, moved: list):
+        ukey, traced = self._jnp_of(p.ufunc)
+        if traced is None:
+            return None, None
+        ops = [self._operand(r, moved) for r in p.args]
+        key, prog = self._map_program(p.ufunc, tuple(s for _, s in ops),
+                                      ukey, traced)
+        self._device_block((p.out_base, p.out_frag.block), moved)
+        args = [v for v, _ in ops]
+        return key, lambda: self._store(p, prog(*args))
+
+    def _stage_fused(self, p: FusedMapReducePayload, moved: list):
+        m = p.map
+        ukey, traced = self._jnp_of(m.ufunc)
+        if traced is None:
+            return None, None
+        ops = [self._operand(r, moved) for r in m.args]
+        sig = tuple(s for _, s in ops)
+        prog = self._program(
+            ("fused",) + ukey + (sig, m.out_frag.shape, str(m.out_dtype),
+                                 p.ufunc_name, p.axes, p.keepdims),
+            lambda: self._jax.jit(_repro_map_reduce(
+                self._jnp, traced, [None if s is None else s[2] for s in sig],
+                m.out_frag.shape,
+                self._jax.dtypes.canonicalize_dtype(m.out_dtype),
+                self._reductions[p.ufunc_name], p)),
+        )
+        args = [v for v, _ in ops]
+
+        def launch():
+            self.scratch[p.dst_scratch] = prog(*args)
+
+        return "n_jit", launch
+
+    def _stage_reduce(self, p: ReducePartialPayload, moved: list):
+        x, sig = self._operand(p.src, moved)
+        prog = self._program(
+            ("reduce", p.ufunc_name, p.axes, p.keepdims, sig[2]),
+            lambda: self._jax.jit(_repro_reduce(
+                self._reductions[p.ufunc_name], sig[2], p)),
+        )
+
+        def launch():
+            self.scratch[p.dst_scratch] = prog(x)
+
+        return "n_jit", launch
+
+    def _stage_combine(self, p: CombinePayload, moved: list):
+        key = (p.out_base, p.out_frag.block)
+        self._device_block(key, moved)
+        part = self.scratch[p.src_scratch]
+        combine = None if p.init else p.ufunc_name
+        return "n_jit", lambda: self._write(key, p.out_frag.local, part,
+                                            combine)
+
+    def _stage_fill(self, p: FillPayload, moved: list):
+        key = (p.out_base, p.out_frag.block)
+        self._device_block(key, moved)
+        return "n_jit", lambda: self._write(key, p.out_frag.local, p.value)
+
+    def _stage_matmul(self, p: MatmulPayload, moved: list):
+        a, sa = self._operand(p.a, moved)
+        b, sb = self._operand(p.b, moved)
+        prog = self._matmul_program(sa[2], sb[2], p.trans_a, p.trans_b)
+        key = (p.out_base, p.out_frag.block)
+        self._device_block(key, moved)
+        combine = None if p.init else "add"
+        return "n_jit", lambda: self._write(key, p.out_frag.local,
+                                            prog(a, b), combine)
+
+    def _exec_host(self, p, fid, moved: list) -> None:
+        """A map (or fused map-reduce) with a ufunc that has no ``jnp``
+        form: download its inputs, run NumPy, upload its result."""
+        read: list = []
+        with _obs.span("exec.host", fid):
+            blocks = _HostCopies(self.storage, read)
+            scratch = _HostCopies(self.scratch, read)
+            if isinstance(p, MapPayload):
+                args = [resolve_ref(r, blocks, scratch) for r in p.args]
+                res = np.asarray(p.ufunc(*args))
+                self._device_block((p.out_base, p.out_frag.block), moved)
+                self._store(p, res)
+            else:
+                execute_payload(p, blocks, scratch)
+                self.scratch[p.dst_scratch] = self._upload(
+                    np.asarray(scratch[p.dst_scratch]))
+        self._count("n_host_untranslated", h2d=sum(moved), d2h=sum(read),
+                    staged=True)
+
+    def transfer(self, p) -> None:
+        """A (coalesced) transfer into scratch.  A block is immutable, so
+        the block as it is at send time, with the fragment's slice, is
+        the snapshot: the program that reads the scratch cuts it, and
+        the transfer copies nothing.  A scratch source is passed on."""
+        moved: list = []
+        parts = (p.transfers if isinstance(p, CoalescedTransferPayload)
+                 else (p,))
+        for t in parts:
+            if t.src[0] == "s":
+                self.scratch[t.dst_scratch] = self.scratch[t.src[1]]
+            else:
+                _, bid, frag = t.src
+                blk = self._device_block((bid, frag.block), moved)
+                self.scratch[t.dst_scratch] = _Sent(blk, frag.local)
+        self._count("n_transfer", h2d=sum(moved), staged=bool(moved))
 
 
 def _flush_of(op: OperationNode):
@@ -396,11 +653,59 @@ def _flush_of(op: OperationNode):
     return None if drain is None else drain.tag
 
 
-def _repro_matmul(jnp):
+# The device programs, named so that a device trace names them
+# (``jit_repro_<kind>``).  Each closes over static slices only.
+
+
+def _repro_fill(jnp, shape, dtype):
+    def repro_fill(value):
+        return jnp.full(shape, value, dtype)
+
+    return repro_fill
+
+
+def _repro_update(jnp, local, combine):
+    def repro_update(blk, value):
+        cur = _cut(blk, local)
+        if combine is not None:
+            value = combine(cur, value)
+        value = jnp.asarray(value).astype(blk.dtype)
+        return blk.at[_slices(local)].set(value)
+
+    return repro_update
+
+
+def _repro_reduce(reduce, local, p: ReducePartialPayload):
+    axes, keepdims = p.axes or None, p.keepdims
+
+    def repro_reduce(x):
+        return reduce(_cut(x, local), axis=axes, keepdims=keepdims)
+
+    repro_reduce.__name__ = f"repro_reduce_{p.ufunc_name}"
+    return repro_reduce
+
+
+def _repro_map_reduce(jnp, traced, cuts, shape, dtype, reduce,
+                      p: FusedMapReducePayload):
+    axes, keepdims = p.axes or None, p.keepdims
+
+    def repro_map_reduce(*args):
+        res = traced(*[_cut(a, c) for a, c in zip(args, cuts)])
+        # the store the unfused pair had: the map's result broadcast
+        # into (and cast to) the output fragment, then reduced
+        res = jnp.broadcast_to(res, shape).astype(dtype)
+        return reduce(res, axis=axes, keepdims=keepdims)
+
+    repro_map_reduce.__name__ = f"{traced.__name__}_reduce_{p.ufunc_name}"
+    return repro_map_reduce
+
+
+def _repro_matmul(jnp, local_a, local_b, trans_a, trans_b):
     """The matmul payload's program, named ``jit_repro_matmul``."""
 
     def repro_matmul(x, y):
-        return jnp.dot(x, y)
+        x, y = _cut(x, local_a), _cut(y, local_b)
+        return jnp.dot(x.T if trans_a else x, y.T if trans_b else y)
 
     return repro_matmul
 
@@ -409,11 +714,13 @@ class AutoBackend(ComputeBackend):
     """Per-payload backend choice — the first registry client beyond the
     two reference backends (ROADMAP "backend autotuning").
 
-    Small block payloads stay on the eager NumPy interpreter (XLA
-    dispatch + host↔device staging costs more than the arithmetic);
-    payloads whose estimated per-element work clears ``threshold`` go to
-    the jit-compiling :class:`JaxBackend` (including its Pallas stencil
-    fast path).  The score is ``out_elements × ufunc cost`` for maps and
+    Blocks stay on the host.  Small block payloads stay on the eager
+    NumPy interpreter (XLA dispatch + host↔device staging costs more
+    than the arithmetic); payloads whose estimated per-element work
+    clears ``threshold`` run on the :class:`JaxBackend`'s programs
+    (including its Pallas stencil fast path): their fragments are
+    uploaded, the program runs, and its result is read back into the
+    host block.  The score is ``out_elements × ufunc cost`` for maps and
     output elements for matmuls — the same per-element weights the
     timeline model uses, so the choice needs no calibration run.  The
     JAX backend is built lazily on the first heavy payload and the
@@ -440,7 +747,8 @@ class AutoBackend(ComputeBackend):
     def _jax_backend(self) -> JaxBackend:
         with self._jax_lock:  # workers race to the first heavy payload
             if self._jax is None:
-                self._jax = JaxBackend(self.storage, self.scratch)
+                # its programs and counters only: it holds no blocks
+                self._jax = JaxBackend({}, {})
             return self._jax
 
     def stats(self) -> dict:
@@ -461,10 +769,51 @@ class AutoBackend(ComputeBackend):
     def execute(self, op: OperationNode) -> None:
         if self._score(op.payload) >= self.threshold:
             self.n_jax += 1
-            self._jax_backend().execute(op)
+            self._exec_staged(op)
             return
         self.n_numpy += 1
         self._numpy.execute(op)
+
+    def _exec_staged(self, op: OperationNode) -> None:
+        """A heavy map or matmul on the JAX backend's programs over host
+        blocks: upload its input fragments, run, read the result back
+        into the host block."""
+        jb = self._jax_backend()
+        p = op.payload
+        fid = _flush_of(op)
+        is_map = isinstance(p, MapPayload)
+        if is_map:
+            ukey, traced = jb._jnp_of(p.ufunc)
+        if is_map and traced is None:
+            with _obs.span("exec.host", fid):
+                self._numpy.execute(op)
+            jb._count("n_host_untranslated")
+            return
+        with _obs.span("exec.stage", fid):
+            refs = p.args if is_map else (p.a, p.b)
+            args = [resolve_ref(r, self.storage, self.scratch) for r in refs]
+            dev = [a if r[0] == "c"
+                   else jb._jax.device_put(np.ascontiguousarray(a))
+                   for r, a in zip(refs, args)]
+            uploaded = [d for r, d in zip(refs, dev) if r[0] != "c"]
+            if is_map:
+                sig = tuple(None if r[0] == "c" else (d.shape, d.dtype, None)
+                            for r, d in zip(refs, dev))
+                key, prog = jb._map_program(p.ufunc, sig, ukey, traced)
+            else:
+                key = "n_jit"
+                prog = jb._matmul_program(None, None, p.trans_a, p.trans_b)
+        with _obs.span("exec.launch", fid):
+            res = prog(*dev)
+        with _obs.span("exec.readback", fid):
+            out = np.asarray(res)
+            blk = self.storage[(p.out_base, p.out_frag.block)]
+            if is_map or p.init:
+                blk[p.out_frag.slices] = out
+            else:
+                blk[p.out_frag.slices] += out
+        jb._count(key, h2d=sum(d.nbytes for d in uploaded), d2h=out.nbytes,
+                  staged=True)
 
 
 register_backend("numpy", NumpyBackend)
@@ -632,8 +981,7 @@ class AsyncExecutor:
     # -- transfer execution (runs on progress threads / workers) ----------
     def _exec_comm(self, op: OperationNode) -> None:
         with _obs.span("channel.transfer", _flush_of(op)):
-            execute_payload(op.payload, self.backend.storage,
-                            self.backend.scratch)
+            self.backend.transfer(op.payload)
 
     # -- work stealing -----------------------------------------------------
     def _steal_for(self, thief: Worker) -> Optional[list[OperationNode]]:
