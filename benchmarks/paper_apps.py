@@ -68,24 +68,41 @@ def black_scholes(n=2_000_000, iters=8):
     return total
 
 
-def nbody(n=2048, steps=4):
-    """Naive O(n²) Newtonian N-body (fig. 13).
+def nbody_state(n):
+    """The text's initial state, in its SI units: masses of 1e5 to 1e6
+    kg and positions in a 1 km square, at rest.  Host float64 ``(n, 1)``
+    arrays by name (``m``, ``px``, ``py``, ``vx``, ``vy``)."""
+    rng = np.random.default_rng(1)
+    m = rng.uniform(1e5, 1e6, (n, 1))
+    px = rng.uniform(0, 1e3, (n, 1))
+    py = rng.uniform(0, 1e3, (n, 1))
+    return dict(m=m, px=px, py=py, vx=np.zeros((n, 1)), vy=np.zeros((n, 1)))
+
+
+def nbody_arrays(state, dtype=np.float64):
+    """An N-body state (host arrays by name, as :func:`nbody_state`
+    gives) as runtime arrays of ``dtype``: the four ``(n, 1)`` vectors,
+    the masses as a column and as a row, and the column of ones the
+    outer products use."""
+    n = state["m"].shape[0]
+    out = {k: dnp.array(np.asarray(state[k], dtype=dtype))
+           for k in ("m", "px", "py", "vx", "vy")}
+    out["m_row"] = dnp.array(np.asarray(state["m"], dtype=dtype).reshape(1, n))
+    out["ones"] = dnp.ones((n, 1), dtype=dtype)
+    return out
+
+
+def nbody_steps(s, steps, G, dt, eps):
+    """``steps`` steps of the text on the runtime arrays ``s``
+    (:func:`nbody_arrays`), updating its vectors in place.
 
     The pairwise matrices are built with SUMMA outer products; the force
     reduction uses broadcast-multiply + axis-sum, which the runtime
     executes as partial-reduce-at-owner + tiny partial transfers — the
     communication-avoiding form of the matvec (paper §6.1.1: the N-body
     matmuls are 'specialized operations')."""
-    rng = np.random.default_rng(1)
-    G, eps, dt = 6.674e-11, 1e-2, 0.1
-    m_np = rng.uniform(1e5, 1e6, (n, 1))
-    m = dnp.array(m_np)
-    m_row = dnp.array(m_np.reshape(1, n))  # the transposed masses
-    px = dnp.array(rng.uniform(0, 1e3, (n, 1)))
-    py = dnp.array(rng.uniform(0, 1e3, (n, 1)))
-    vx = dnp.zeros((n, 1))
-    vy = dnp.zeros((n, 1))
-    ones = dnp.ones((n, 1))
+    m, m_row, ones = s["m"], s["m_row"], s["ones"]
+    px, py, vx, vy = s["px"], s["py"], s["vx"], s["vy"]
 
     def pairwise(a):
         A = dnp.matmul(a, ones, trans_b=True)  # [i, j] = a[i]
@@ -103,7 +120,45 @@ def nbody(n=2048, steps=4):
         vy += dt * fy / m
         px += dt * vx
         py += dt * vy
-    return px
+
+
+def nbody(n=2048, steps=4):
+    """Naive O(n²) Newtonian N-body (fig. 13) in two dimensions: the
+    text's initial state, float64, stepped in its SI units; returns the
+    x positions."""
+    s = nbody_arrays(nbody_state(n))
+    nbody_steps(s, steps, G=6.674e-11, dt=0.1, eps=1e-2)
+    return s["px"]
+
+
+NBODY_REFERENCE_BAND = 256  # rows of the reference's pairwise terms at a time
+
+
+def nbody_reference(state, steps, params):
+    """The steps of :func:`nbody_steps` on ``state`` (host arrays by
+    name) in float64 NumPy, without the runtime: the pairwise terms of
+    :data:`NBODY_REFERENCE_BAND` rows at a time.  ``params`` holds
+    ``G``, ``dt`` and ``eps``; returns the state after ``steps``
+    steps."""
+    G, dt, eps = params["G"], params["dt"], params["eps"]
+    m, px, py, vx, vy = (np.asarray(state[k], np.float64)[:, 0]
+                         for k in ("m", "px", "py", "vx", "vy"))
+    n = m.size
+    for _ in range(steps):
+        fx, fy = np.empty(n), np.empty(n)
+        for i0 in range(0, n, NBODY_REFERENCE_BAND):
+            rows = slice(i0, min(i0 + NBODY_REFERENCE_BAND, n))
+            dx = px[None, :] - px[rows, None]
+            dy = py[None, :] - py[rows, None]
+            inv_r3 = (dx * dx + dy * dy + eps) ** -1.5
+            fx[rows] = G * m[rows] * (dx * inv_r3 * m).sum(axis=1)
+            fy[rows] = G * m[rows] * (dy * inv_r3 * m).sum(axis=1)
+        vx = vx + dt * fx / m
+        vy = vy + dt * fy / m
+        px = px + dt * vx
+        py = py + dt * vy
+    return {k: v[:, None] for k, v in
+            dict(m=m, px=px, py=py, vx=vx, vy=vy).items()}
 
 
 def knn(n=4096, d=64):

@@ -199,10 +199,15 @@ class JaxBackend(ComputeBackend):
       downloads its inputs, runs NumPy, and uploads its result through
       :meth:`_store`.
 
-    :meth:`stats` counts payloads per path, transfers, the payloads that
-    moved bytes between host and device (``n_staged``: fallbacks and
-    first-touch uploads) and those bytes, readbacks included.  Programs
-    are named after their kind (``repro_map_<ufunc>``,
+    * **Matmuls at their dtype's precision.**  A float32 product runs
+      at ``Precision.HIGHEST``; other dtypes keep XLA's default (on a
+      TPU, one bfloat16 pass).
+
+    :meth:`stats` counts payloads per path, matmul and combine payloads
+    among them (``n_matmul``, ``n_combine``), transfers, the payloads
+    that moved bytes between host and device (``n_staged``: fallbacks
+    and first-touch uploads) and those bytes, readbacks included.
+    Programs are named after their kind (``repro_map_<ufunc>``,
     ``repro_stencil5``, ``repro_update``, ``repro_matmul``, ...), so a
     device trace names them.
 
@@ -210,7 +215,9 @@ class JaxBackend(ComputeBackend):
     payload runs under stage spans: ``exec.stage`` (resolving refs into
     the program's arguments, first-touch uploads included),
     ``exec.launch`` (the program's dispatch and the block update), and
-    ``exec.host`` for a payload that runs on NumPy.
+    ``exec.host`` for a payload that runs on NumPy.  A combine's
+    staging and its launch each run under an ``exec.combine`` span too,
+    nested in its ``exec.stage`` and ``exec.launch``: two a combine.
 
     Note: without ``jax_enable_x64`` the payloads compute in float32, so
     results are *numerically close*, not bit-identical, to the NumPy
@@ -277,6 +284,8 @@ class JaxBackend(ComputeBackend):
             n_pallas=0,  # fused stencils run through the Pallas kernel
             n_host_untranslated=0,  # maps with no jnp form, run by NumPy
             n_transfer=0,  # transfers, run on the channel's threads
+            n_matmul=0,  # matmul payloads (also in n_jit)
+            n_combine=0,  # combines of partial reductions (also in n_jit)
             n_staged=0,  # payloads that moved bytes host<->device
             h2d_bytes=0,  # first-touch uploads and host results
             d2h_bytes=0,  # readbacks at gather and fallback inputs
@@ -284,16 +293,22 @@ class JaxBackend(ComputeBackend):
 
     # -- helpers ---------------------------------------------------------
     def _count(self, key: Optional[str] = None, h2d: int = 0, d2h: int = 0,
-               staged: bool = False) -> None:
+               staged: bool = False, kind: Optional[str] = None) -> None:
+        """Bump the path counter ``key``, the payload-kind counter
+        ``kind`` (``n_matmul``, ``n_combine``) and the byte counters."""
         with self._count_lock:
             c = self._counts
             if key is not None:
                 c[key] += 1
+            if kind is not None:
+                c[kind] += 1
             c["n_staged"] += staged
             c["h2d_bytes"] += h2d
             c["d2h_bytes"] += d2h
         _obs.count("h2d_bytes", h2d)  # a profiler session's share
         _obs.count("d2h_bytes", d2h)
+        if kind is not None:
+            _obs.count(kind, 1)
 
     def stats(self) -> dict:
         """Payload counts per execution path, transfers, staged payloads,
@@ -515,26 +530,33 @@ class JaxBackend(ComputeBackend):
         prog.__name__ = traced.__name__
         return "n_jit", self._jax.jit(prog)
 
-    def _matmul_program(self, local_a, local_b, trans_a, trans_b):
+    def _matmul_program(self, local_a, local_b, trans_a, trans_b, dtype):
+        """The matmul program for operands whose product is of ``dtype``.
+        A float32 product multiplies at float32 precision (``HIGHEST``):
+        at the default, a TPU runs a product over an inner dimension of 2
+        or more as a single bfloat16 pass."""
+        precision = (self._jax.lax.Precision.HIGHEST
+                     if dtype == np.float32 else None)
         return self._program(
-            ("matmul", local_a, local_b, trans_a, trans_b),
+            ("matmul", local_a, local_b, trans_a, trans_b, precision),
             lambda: self._jax.jit(_repro_matmul(self._jnp, local_a, local_b,
-                                                trans_a, trans_b)),
+                                                trans_a, trans_b, precision)),
         )
 
     # -- execution -------------------------------------------------------
     def execute(self, op: OperationNode) -> None:
         p = op.payload
         fid = _flush_of(op)
+        kind = _KIND_COUNTERS.get(type(p))
         moved: list = []
-        with _obs.span("exec.stage", fid):
+        with _obs.span("exec.stage", fid), _combine_span(p, fid):
             key, launch = self._stages[type(p)](p, moved)
         if launch is None:  # a ufunc in it has no jnp form
             self._exec_host(p, fid, moved)
             return
-        with _obs.span("exec.launch", fid):
+        with _obs.span("exec.launch", fid), _combine_span(p, fid):
             launch()
-            self._count(key, h2d=sum(moved), staged=bool(moved))
+            self._count(key, h2d=sum(moved), staged=bool(moved), kind=kind)
 
     # Each stage resolves a payload's refs into its program's arguments
     # and returns ``(counter key, launch)``; ``launch`` dispatches the
@@ -603,7 +625,8 @@ class JaxBackend(ComputeBackend):
     def _stage_matmul(self, p: MatmulPayload, moved: list):
         a, sa = self._operand(p.a, moved)
         b, sb = self._operand(p.b, moved)
-        prog = self._matmul_program(sa[2], sb[2], p.trans_a, p.trans_b)
+        prog = self._matmul_program(sa[2], sb[2], p.trans_a, p.trans_b,
+                                    self._jnp.result_type(sa[1], sb[1]))
         key = (p.out_base, p.out_frag.block)
         self._device_block(key, moved)
         combine = None if p.init else "add"
@@ -653,6 +676,18 @@ def _flush_of(op: OperationNode):
     return None if drain is None else drain.tag
 
 
+# payload kinds counted beside their path (``JaxBackend.stats()``)
+_KIND_COUNTERS = {MatmulPayload: "n_matmul", CombinePayload: "n_combine"}
+
+
+def _combine_span(p, fid):
+    """``exec.combine`` for a combine payload, nested in its
+    ``exec.stage`` and ``exec.launch``; the null span otherwise."""
+    if type(p) is CombinePayload:
+        return _obs.span("exec.combine", fid)
+    return _obs.NO_SPAN
+
+
 # The device programs, named so that a device trace names them
 # (``jit_repro_<kind>``).  Each closes over static slices only.
 
@@ -700,12 +735,13 @@ def _repro_map_reduce(jnp, traced, cuts, shape, dtype, reduce,
     return repro_map_reduce
 
 
-def _repro_matmul(jnp, local_a, local_b, trans_a, trans_b):
+def _repro_matmul(jnp, local_a, local_b, trans_a, trans_b, precision):
     """The matmul payload's program, named ``jit_repro_matmul``."""
 
     def repro_matmul(x, y):
         x, y = _cut(x, local_a), _cut(y, local_b)
-        return jnp.dot(x.T if trans_a else x, y.T if trans_b else y)
+        return jnp.dot(x.T if trans_a else x, y.T if trans_b else y,
+                       precision=precision)
 
     return repro_matmul
 
@@ -802,7 +838,9 @@ class AutoBackend(ComputeBackend):
                 key, prog = jb._map_program(p.ufunc, sig, ukey, traced)
             else:
                 key = "n_jit"
-                prog = jb._matmul_program(None, None, p.trans_a, p.trans_b)
+                prog = jb._matmul_program(
+                    None, None, p.trans_a, p.trans_b,
+                    jb._jnp.result_type(*(d.dtype for d in dev)))
         with _obs.span("exec.launch", fid):
             res = prog(*dev)
         with _obs.span("exec.readback", fid):
@@ -813,7 +851,7 @@ class AutoBackend(ComputeBackend):
             else:
                 blk[p.out_frag.slices] += out
         jb._count(key, h2d=sum(d.nbytes for d in uploaded), d2h=out.nbytes,
-                  staged=True)
+                  staged=True, kind=None if is_map else "n_matmul")
 
 
 register_backend("numpy", NumpyBackend)

@@ -16,9 +16,10 @@ from repro.obs import collector as obs_collector
 from repro.obs import trace
 
 # every payload of the program below has a device form, so none runs
-# under "exec.host"; "exec.readback" is the read at gather
+# under "exec.host"; "exec.readback" is the read at gather, and the
+# reduction's combines run "exec.combine"
 SPANS = {"record.insert", "plan", "exec.stage", "exec.launch",
-         "exec.readback", "channel.transfer"}
+         "exec.readback", "channel.transfer", "exec.combine"}
 EXEC_SPANS = ("exec.stage", "exec.launch", "exec.readback", "exec.host")
 
 
@@ -199,7 +200,8 @@ def _profiled_program(trace_dir, n=32):
 def test_a_profiler_alone_turns_the_spans_on(tmp_path):
     """With no collector, the spans run while a JAX profiler records:
     each lands in its trace, and the session's totals hold them and the
-    bytes and dependency scans the runtime counted meanwhile."""
+    bytes, dependency scans, matmuls and combines the runtime counted
+    meanwhile."""
     _program(32)  # compile, with no profiler recording
     assert obs_collector.span("plan") is obs_collector.NO_SPAN
     events, _, backend, spans = _profiled_program(tmp_path)
@@ -216,7 +218,8 @@ def test_a_profiler_alone_turns_the_spans_on(tmp_path):
         assert counts[name] == n, name
         assert abs(seconds[name] - s) <= max(0.05 * s, 1e-3), name
     assert session["counters"] == {
-        k: backend[k] for k in ("h2d_bytes", "d2h_bytes", "scan_steps")}
+        k: backend[k] for k in ("h2d_bytes", "d2h_bytes", "scan_steps",
+                                "n_matmul", "n_combine")}
 
 
 def test_each_profiler_session_sums_afresh(tmp_path):

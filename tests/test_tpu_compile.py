@@ -1,4 +1,5 @@
-"""The main path's Pallas kernels compiled for a described TPU v5e chip.
+"""The main path's Pallas kernels, and the matmul payload's precision,
+compiled for a described TPU v5e chip.
 
 Nothing runs: the installed TPU compiler compiles each kernel at a real
 size for a chip that is described, not attached, and refuses what the
@@ -67,6 +68,24 @@ def test_stencil5_block_compiles_for_runtime_blocks(one_chip, shape):
         one_chip, *[(shape, jnp.float32)] * 5,
     )
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("k", [1, 1024])
+def test_float32_matmul_payload_takes_no_bfloat16_pass(one_chip, k):
+    """The matmul payload's program for a float32 product (``HIGHEST``)
+    on runtime blocks: a product over an inner dimension of 1 (the
+    N-body outer products) compiles to an f32 broadcast-multiply, one
+    over 1024 to a convolution at the highest operand precision."""
+    from repro.exec.backend import _repro_matmul
+
+    hlo = _compiled_hlo(
+        _repro_matmul(jnp, None, None, False, True, jax.lax.Precision.HIGHEST),
+        one_chip, *[((1024, k), jnp.float32)] * 2,
+    )
+    if k == 1:
+        assert "convolution" not in hlo and "multiply" in hlo
+    else:
+        assert "operand_precision={highest,highest}" in hlo
 
 
 def test_flash_attention_compiles_gqa(one_chip):
