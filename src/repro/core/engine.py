@@ -179,29 +179,38 @@ class FusedMapReducePayload:
 class BlockStore(dict):
     """The runtime's block storage: ``(base_id, coord) -> block``.
 
-    ``scatter`` and ``fill_base`` write host ``np.ndarray`` blocks.  A
+    ``scatter`` writes host ``np.ndarray`` blocks.  ``fill_base`` does
+    too, except where the blocks live on the device: there it stores a
+    fill record (:func:`fill_record`), which holds no host bytes.  A
     device backend (``repro.exec.JaxBackend``) replaces a block with a
-    device array the first time a payload touches it, and from then on
-    the block lives on the device.  Two side tables serve that move:
-
-    * ``fills``: the value each ``fill_base`` block was filled with, so
-      a device backend creates such a block on the device instead of
-      uploading it;
-    * ``declared``: the program's dtype of each device block that
-      computes in a narrower one (float64 as float32 without x64), so
-      that the planner and the plan cache read the dtype they read
-      before the move (:func:`block_dtype`).
+    device array the first time a payload touches it, made from the
+    record where the block is one, and from then on the block lives on
+    the device.  ``declared`` keeps the program's dtype of each device
+    block that computes in a narrower one (float64 as float32 without
+    x64), so that the planner and the plan cache read the dtype they
+    read before the move (:func:`block_dtype`).
     """
 
     def __init__(self):
         super().__init__()
-        self.fills: dict = {}
         self.declared: dict = {}
 
     def __delitem__(self, key) -> None:
         super().__delitem__(key)
-        self.fills.pop(key, None)
         self.declared.pop(key, None)
+
+
+def fill_record(shape, dtype, value) -> np.ndarray:
+    """A block of ``shape`` and ``dtype`` holding ``value`` everywhere,
+    with one element's bytes: a read-only view with zero strides.  Host
+    readers read it as the filled block; nothing writes into it."""
+    return np.broadcast_to(np.full((), value, dtype), shape)
+
+
+def is_fill_record(blk: np.ndarray) -> bool:
+    """Whether host block ``blk`` is a :func:`fill_record`: the store's
+    only read-only blocks."""
+    return not blk.flags.writeable
 
 
 def block_dtype(storage: dict, key) -> Optional[np.dtype]:
@@ -635,6 +644,11 @@ class Runtime:
                     f"unknown exec_channel {self.exec_channel!r} "
                     f"(registered: {', '.join(CHANNELS.available())})"
                 )
+        # a backend that keeps its blocks on the device (``lazy_fills``)
+        # makes a filled block there: ``fill_base`` writes no host bytes
+        self._lazy_fills = flush_backend == "async" and getattr(
+            BACKENDS.get(exec_backend) if isinstance(exec_backend, str)
+            else exec_backend, "lazy_fills", False)
         if isinstance(exec_latency, str):
             from repro.comm.emulation import resolve_latency
 
@@ -741,6 +755,7 @@ class Runtime:
         # submitting threads plan (and verify) concurrently
         self._stats_lock = threading.Lock()
         self._scan_steps = 0  # of dependency systems retired so far
+        self.fill_host_bytes = 0  # what fill_base wrote on the host
         # guards lazy executor/backend/channel construction (first
         # concurrent submit_cone calls race to build them)
         self._exec_lock = threading.Lock()
@@ -904,12 +919,22 @@ class Runtime:
             self.storage[(base.id, coord)] = np.array(data[sl], copy=True)
 
     def fill_base(self, base: ArrayBase, value) -> None:
+        """Every block of ``base`` filled with ``value``: a fill record
+        where the backend makes its blocks on the device, a host block
+        that payloads write in place everywhere else."""
+        written = 0
         for coord, _ in base.layout.blocks():
-            key = (base.id, coord)
-            self.storage[key] = np.full(
-                base.layout.block_shape_at(coord), value, dtype=base.dtype
-            )
-            self.storage.fills[key] = value
+            shape = base.layout.block_shape_at(coord)
+            if self._lazy_fills:
+                blk = fill_record(shape, base.dtype, value)
+            else:
+                blk = np.full(shape, value, dtype=base.dtype)
+                written += blk.nbytes
+            self.storage[(base.id, coord)] = blk
+        if written:
+            with self._stats_lock:
+                self.fill_host_bytes += written
+            _obs.count("fill_host_bytes", written)
 
     def gather(self, base: ArrayBase, view: ViewSpec) -> np.ndarray:
         """Read back a view (flushes first — §5.6 trigger 1).
@@ -1980,13 +2005,15 @@ class Runtime:
     # -- reporting -------------------------------------------------------------
     def backend_stats(self) -> dict:
         """The async compute backend's per-path payload counters (see
-        ``JaxBackend.stats``) and the runtime's dependency-list
-        :attr:`scan_steps`; empty before the first async drain and after
-        :meth:`close`."""
+        ``JaxBackend.stats``), the runtime's dependency-list
+        :attr:`scan_steps` and the bytes ``fill_base`` wrote on the host
+        (``fill_host_bytes``); empty before the first async drain and
+        after :meth:`close`."""
         backend = self._exec_backend_obj
         if backend is None:
             return {}
-        return dict(backend.stats(), scan_steps=self.scan_steps)
+        return dict(backend.stats(), scan_steps=self.scan_steps,
+                    fill_host_bytes=self.fill_host_bytes)
 
     def span_totals(self) -> dict:
         """``{stage: (count, wall seconds)}`` of the runtime's stage

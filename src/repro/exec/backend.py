@@ -50,6 +50,7 @@ from repro.core.engine import (
     MatmulPayload,
     ReducePartialPayload,
     execute_payload,
+    is_fill_record,
     resolve_ref,
 )
 from repro.core.graph import COMM, DependencySystem, OperationNode
@@ -81,6 +82,10 @@ class ComputeBackend:
     """Executes operation payloads against the runtime's block storage."""
 
     name = "abstract"
+    # whether the backend makes a filled block from the runtime's fill
+    # record (``repro.core.engine.fill_record``) instead of writing into
+    # a filled host block
+    lazy_fills = False
 
     def __init__(self, storage: dict, scratch: dict):
         self.storage = storage
@@ -146,7 +151,8 @@ class _Sent(NamedTuple):
 class _HostCopies(dict):
     """Host copies of the blocks (or scratch buffers) of ``src`` that a
     host fallback reads, each downloaded on its first access; the bytes
-    read from the device go to ``moved``."""
+    read from the device go to ``moved``.  A host block, a fill record
+    among them, is read where it is."""
 
     def __init__(self, src: dict, moved: list):
         super().__init__()
@@ -169,9 +175,11 @@ class JaxBackend(ComputeBackend):
     * **Blocks live on the device.**  The first payload that touches a
       block holding a host ``np.ndarray`` uploads it once, in the dtype
       the device computes in, and replaces the storage entry with the
-      device array; a block that ``fill_base`` made is created on the
-      device instead of uploaded.  Bytes cross to the host only where
-      the program reads values (:meth:`readback`, called by
+      device array.  ``fill_base`` writes no host block for this
+      backend (:attr:`lazy_fills`): it stores a fill record, and the
+      first payload that touches the block makes it on the device from
+      the record (``n_fill_device``).  Bytes cross to the host only
+      where the program reads values (:meth:`readback`, called by
       ``Runtime.gather``) and where a payload has no device form.
     * **One program per payload shape.**  Each payload is a jitted
       program over whole blocks, cut to its fragments inside the
@@ -204,7 +212,8 @@ class JaxBackend(ComputeBackend):
       TPU, one bfloat16 pass).
 
     :meth:`stats` counts payloads per path, matmul and combine payloads
-    among them (``n_matmul``, ``n_combine``), transfers, the payloads
+    among them (``n_matmul``, ``n_combine``), transfers, blocks made on
+    the device from a fill record (``n_fill_device``), the payloads
     that moved bytes between host and device (``n_staged``: fallbacks
     and first-touch uploads) and those bytes, readbacks included.
     Programs are named after their kind (``repro_map_<ufunc>``,
@@ -225,6 +234,7 @@ class JaxBackend(ComputeBackend):
     """
 
     name = "jax"
+    lazy_fills = True
 
     def __init__(self, storage: dict, scratch: dict):
         super().__init__(storage, scratch)
@@ -286,6 +296,7 @@ class JaxBackend(ComputeBackend):
             n_transfer=0,  # transfers, run on the channel's threads
             n_matmul=0,  # matmul payloads (also in n_jit)
             n_combine=0,  # combines of partial reductions (also in n_jit)
+            n_fill_device=0,  # blocks made on the device from a fill record
             n_staged=0,  # payloads that moved bytes host<->device
             h2d_bytes=0,  # first-touch uploads and host results
             d2h_bytes=0,  # readbacks at gather and fallback inputs
@@ -294,8 +305,9 @@ class JaxBackend(ComputeBackend):
     # -- helpers ---------------------------------------------------------
     def _count(self, key: Optional[str] = None, h2d: int = 0, d2h: int = 0,
                staged: bool = False, kind: Optional[str] = None) -> None:
-        """Bump the path counter ``key``, the payload-kind counter
-        ``kind`` (``n_matmul``, ``n_combine``) and the byte counters."""
+        """Bump the path counter ``key``, the counter ``kind`` that a
+        profiler session sums too (``n_matmul``, ``n_combine``,
+        ``n_fill_device``) and the byte counters."""
         with self._count_lock:
             c = self._counts
             if key is not None:
@@ -343,14 +355,14 @@ class JaxBackend(ComputeBackend):
         return blk
 
     def _migrate(self, key, host: np.ndarray, moved: list):
-        fills = getattr(self.storage, "fills", {})
-        if key in fills:
+        if is_fill_record(host):
             dtype = self._jax.dtypes.canonicalize_dtype(host.dtype)
             full = self._program(
                 ("full", host.shape, dtype),
                 lambda: self._jax.jit(_repro_fill(self._jnp, host.shape, dtype)),
             )
-            dev = full(fills.pop(key))
+            dev = full(host[(0,) * host.ndim])
+            self._count(kind="n_fill_device")
         else:
             dev = self._jax.device_put(host)
             moved.append(host.nbytes)

@@ -18,6 +18,7 @@ from repro.core.engine import (
     MapPayload,
     TransferPayload,
     block_dtype,
+    is_fill_record,
 )
 from repro.core.ufunc import get_ufunc
 from repro.exec import JaxBackend
@@ -212,3 +213,145 @@ def test_dead_temporaries_are_recycled_between_barriers():
             c[:] = work  # still pending at the next read
             sizes.append((len(rt.storage), len(rt.scratch)))
     assert sizes[1] == sizes[2] == sizes[3]
+
+
+# the creation routines that call ``fill_base``, each with the value it
+# fills with
+_FILLS = {
+    "empty": (lambda shape: repro.empty(shape, dtype=np.float32), 0.0),
+    "zeros": (lambda shape: repro.zeros(shape, dtype=np.float32), 0.0),
+    "full": (lambda shape: repro.full(shape, 2.5, dtype=np.float32), 2.5),
+}
+
+
+def _blocks(rt, arr):
+    return {k: b for k, b in rt.storage.items() if k[0] == arr._base.id}
+
+
+@pytest.mark.parametrize("make", list(_FILLS))
+def test_fills_hold_no_host_block_and_are_made_on_first_touch(make):
+    """On the device path a filled array is fill records, no writable
+    host block; the payloads of a drain make on the device exactly the
+    blocks they touch, and no fill writes host bytes."""
+    create, value = _FILLS[make]
+    with _jax_runtime() as rt:
+        t = create((64, 64))  # 16 blocks of 16x16
+        assert not any(isinstance(b, np.ndarray) and b.flags.writeable
+                       for b in rt.storage.values())
+        assert all(is_fill_record(b) for b in _blocks(rt, t).values())
+        top = t[:32] * 2.0  # reads t's 8 upper blocks, writes 8 blocks
+        got = np.asarray(top)
+        stats = rt.backend_stats()
+        on_device = [k for k, b in rt.storage.items()
+                     if not isinstance(b, np.ndarray)]
+        lazy = [k for k, b in _blocks(rt, t).items()
+                if isinstance(b, np.ndarray) and is_fill_record(b)]
+    np.testing.assert_array_equal(got, np.full((32, 64), 2 * value))
+    assert stats["n_fill_device"] == len(on_device) == 16
+    assert len(lazy) == 8  # the lower half, which no payload touched
+    assert stats["fill_host_bytes"] == 0 and stats["h2d_bytes"] == 0
+
+
+def test_an_untouched_fill_reads_as_its_value_and_stays_lazy():
+    """``np.asarray`` of a filled array no payload touched reads the
+    value from the fill record: no readback, no host copy stored."""
+    with _jax_runtime() as rt:
+        t = repro.full((48, 40), 3.0)
+        with trace() as tr:
+            got = np.asarray(t)
+        spans = tr.span_totals()
+        blocks = _blocks(rt, t)
+        stats = rt.backend_stats()
+    np.testing.assert_array_equal(got, np.full((48, 40), 3.0))
+    assert got.dtype == np.float64
+    assert blocks and all(is_fill_record(b) for b in blocks.values())
+    assert "exec.readback" not in spans
+    assert stats.get("d2h_bytes", 0) == 0
+    assert stats.get("fill_host_bytes", 0) == 0
+
+
+def test_a_float64_fill_keeps_its_declared_dtype_without_x64():
+    """The planner reads float64 for a float64 fill before its first
+    touch (the record's dtype) and after it (the store's ``declared``),
+    though the device block is float32."""
+    assert not jax.config.jax_enable_x64
+    with _jax_runtime() as rt:
+        t = repro.zeros((32, 32))
+        key = (t._base.id, (0, 0))
+        before = block_dtype(rt.storage, key)
+        t += 1.0
+        got = np.asarray(t)
+        after = block_dtype(rt.storage, key)
+        dev = rt.storage[key].dtype
+    assert before == after == np.float64 and dev == np.float32
+    np.testing.assert_array_equal(got, np.ones((32, 32)))
+
+
+@pytest.mark.parametrize("reduced", [False, True], ids=["map", "map_reduce"])
+def test_a_host_fallback_reads_a_fill_record(reduced):
+    """A ufunc with no ``jnp`` form over a filled array no payload
+    touched runs on NumPy over the fill records, downloads nothing, and
+    gives NumPy's answer."""
+    want_block = np.exp(np.full((32, 32), 0.5, dtype=np.float32))
+    want = want_block.sum() if reduced else want_block
+    with _jax_runtime(nprocs=2) as rt:
+        np.asarray(repro.array(np.ones((4, 4), np.float32)) + 1.0)
+        rt._exec_backend_obj._impls.pop("exp")
+        a = repro.full((32, 32), 0.5, dtype=np.float32)
+        before = rt.backend_stats()
+        got = float(np.sum(np.exp(a))) if reduced else np.asarray(np.exp(a))
+        after = rt.backend_stats()
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    assert after["n_host_untranslated"] - before["n_host_untranslated"] >= 4
+    # the result's read at gather alone
+    assert after["d2h_bytes"] - before["d2h_bytes"] == np.asarray(want).nbytes
+    assert after["fill_host_bytes"] == 0
+
+
+def test_disjoint_writers_of_one_fill_record_give_the_numpy_grid():
+    """16 workers write the rows of one block that is still a fill
+    record: they race to make it on the device, and every row lands as
+    the NumPy backend writes it."""
+    n = 64
+    src = np.arange(n * n, dtype=np.float32).reshape(n, n)
+    grids = {}
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for backend in ("numpy", "jax"):
+            with repro.runtime(
+                    RuntimeConfig(nprocs=16, block_size=n),
+                    ExecutionPolicy(flush="async", backend=backend, passes=(),
+                                    steal_threshold=2, steal_latency=0.0)):
+                x = repro.array(src)
+                out = repro.full((n, n), -1.0, dtype=np.float32)
+                for i in range(0, n, 2):
+                    out[i, :] = x[i, :] * 2.0
+                grids[backend] = np.asarray(out)
+    finally:
+        sys.setswitchinterval(prev)
+    want = src * 2.0
+    want[1::2] = -1.0
+    np.testing.assert_array_equal(grids["numpy"], want)
+    np.testing.assert_array_equal(grids["jax"], grids["numpy"])
+
+
+@pytest.mark.parametrize("flush,backend", [
+    ("async", "numpy"), ("async", "auto"), ("sim", "numpy"), ("sim", "jax"),
+])
+def test_host_paths_keep_writable_filled_blocks(flush, backend):
+    """Where payloads write host blocks in place (the NumPy and auto
+    backends, the simulated flush) ``fill_base`` writes them eagerly and
+    counts their bytes."""
+    with repro.runtime(RuntimeConfig(nprocs=4, block_size=16),
+                       ExecutionPolicy(flush=flush, backend=backend)) as rt:
+        t = repro.zeros((32, 32))
+        blocks = _blocks(rt, t)
+        assert len(blocks) == 4
+        for b in blocks.values():
+            assert b.flags.writeable and not is_fill_record(b)
+            np.testing.assert_array_equal(b, np.zeros((16, 16)))
+        assert rt.fill_host_bytes == 32 * 32 * 8
+        t += 1.0
+        got = np.asarray(t)
+    np.testing.assert_array_equal(got, np.ones((32, 32)))

@@ -200,8 +200,8 @@ def _profiled_program(trace_dir, n=32):
 def test_a_profiler_alone_turns_the_spans_on(tmp_path):
     """With no collector, the spans run while a JAX profiler records:
     each lands in its trace, and the session's totals hold them and the
-    bytes, dependency scans, matmuls and combines the runtime counted
-    meanwhile."""
+    bytes, dependency scans, matmuls, combines and blocks made from
+    fill records the runtime counted meanwhile."""
     _program(32)  # compile, with no profiler recording
     assert obs_collector.span("plan") is obs_collector.NO_SPAN
     events, _, backend, spans = _profiled_program(tmp_path)
@@ -219,7 +219,7 @@ def test_a_profiler_alone_turns_the_spans_on(tmp_path):
         assert abs(seconds[name] - s) <= max(0.05 * s, 1e-3), name
     assert session["counters"] == {
         k: backend[k] for k in ("h2d_bytes", "d2h_bytes", "scan_steps",
-                                "n_matmul", "n_combine")}
+                                "n_matmul", "n_combine", "n_fill_device")}
 
 
 def test_each_profiler_session_sums_afresh(tmp_path):
